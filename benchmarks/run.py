@@ -100,10 +100,13 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.core import TraceSession
+    from repro.launch.mesh import enable_compile_cache
     from repro.tune.policy import load_policy
 
     from . import (bench_dma, bench_graphs, bench_kv, bench_loadtest,
                    bench_policy, bench_submission)
+
+    enable_compile_cache()
 
     sections: Dict[str, Dict[str, Any]] = {}
 

@@ -4,10 +4,8 @@ Model code (e.g. the shard_map MoE) needs the active mesh + data-parallel
 axis names; launchers set them here.  Kept explicit (not jax's global mesh)
 so models stay traceable without a mesh for single-device tests.
 
-Also hosts :func:`shard_map` — a version-compat wrapper over
-``jax.shard_map`` (jax ≥ 0.5, ``check_vma=``) and
-``jax.experimental.shard_map.shard_map`` (older jax, ``check_rep=``) —
-and the process-identity helpers (:func:`process_info`,
+Also hosts :func:`shard_map` (``jax.shard_map`` with keyword specs) and
+the process-identity helpers (:func:`process_info`,
 :func:`process_tags`) that fleet launchers use to tag their per-process
 :class:`~repro.core.session.TraceSession` so JSONL shards identify
 themselves to :mod:`repro.obs.aggregate`.
@@ -28,19 +26,8 @@ __all__ = ["set_mesh", "get_mesh", "dp_axes_active", "shard_map",
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        # mid-range jax promoted shard_map to the top level before renaming
-        # check_rep= to check_vma= — probe both spellings
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def set_mesh(mesh, dp_axes: Tuple[str, ...]) -> None:
@@ -70,10 +57,7 @@ def process_info() -> Dict[str, Any]:
         idx = int(env_idx)
         count = int(os.environ.get("REPRO_PROCESS_COUNT", idx + 1))
     else:
-        try:
-            idx, count = jax.process_index(), jax.process_count()
-        except Exception:       # jax not initialized / very old API
-            idx, count = 0, 1
+        idx, count = jax.process_index(), jax.process_count()
     return {"host": socket.gethostname(), "process": int(idx),
             "process_count": int(count)}
 

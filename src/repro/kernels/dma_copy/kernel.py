@@ -71,8 +71,8 @@ def dma_copy_explicit(x: jax.Array, block_rows: int = 256,
     return pl.pallas_call(
         kernel,
         grid=(R // block_rows,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((R, C), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_rows, C), x.dtype),

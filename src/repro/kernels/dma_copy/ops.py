@@ -5,21 +5,15 @@ from functools import partial
 
 import jax
 
+from .. import interpret_mode
 from .kernel import dma_copy_explicit, dma_copy_pipelined
 
 __all__ = ["dma_copy"]
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 @partial(jax.jit, static_argnames=("mode", "block_rows"))
 def dma_copy(x, mode: str = "pipelined", block_rows: int = 256):
-    interp = not _on_tpu()
+    interp = interpret_mode()
     if mode == "explicit":
         return dma_copy_explicit(x, block_rows=block_rows, interpret=interp)
     return dma_copy_pipelined(x, block_rows=block_rows, interpret=interp)

@@ -8,9 +8,12 @@ the kernel streams KV blocks with a fori_loop carrying the running
 grid index — the causal-skip halves compute vs the masked dense loop.
 
 Block sizes default to (128, 128): the MXU is 128×128 and hd ∈ {64,128,256}
-for every assigned arch, so panels are hardware-aligned.  VMEM footprint per
-step ≈ block_q·hd (q) + 2·block_k·hd (kv) + block_q·block_k (scores) floats —
-well under the ~16 MiB/core VMEM budget for all supported shapes.
+for every assigned arch, so panels are hardware-aligned.  K and V are NOT
+streamed over the grid: each grid cell's K and V blocks are the head's whole
+sequence, ``(S, hd)``, held in VMEM, and the fori_loop walks it in block_k
+slices.  That fits at S=4096, hd=256 (2 MiB per operand in bf16), but at
+S=32768 the whole-sequence blocks exceed VMEM and the TPU compiler refuses
+the kernel.  Streaming K/V blocks over a grid axis would lift that limit.
 """
 from __future__ import annotations
 
@@ -46,10 +49,9 @@ def flash_attention_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int,
 
     def body(j, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k),
-                            pl.dslice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k),
-                            pl.dslice(None))).astype(jnp.float32)
+        rows = pl.ds(j * block_k, block_k)
+        k = k_ref[rows, :].astype(jnp.float32)
+        v = v_ref[rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:

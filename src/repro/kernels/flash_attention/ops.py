@@ -1,7 +1,7 @@
 """jit'd public wrapper for the flash attention kernel.
 
-On CPU (tests, this container) the kernel body executes in interpret mode;
-on TPU it compiles to Mosaic.  The oracle is ``ref.flash_attention_ref``.
+On the CPU backend the kernel body executes in interpret mode; on TPU it
+compiles to Mosaic.  The oracle is ``ref.flash_attention_ref``.
 """
 from __future__ import annotations
 
@@ -9,20 +9,14 @@ from functools import partial
 
 import jax
 
+from .. import interpret_mode
 from .kernel import flash_attention_pallas
 
 __all__ = ["flash_attention"]
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k"))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
                     block_k: int = 128):
     return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=not _on_tpu())
+                                  block_k=block_k, interpret=interpret_mode())

@@ -5,19 +5,13 @@ from functools import partial
 
 import jax
 
+from .. import interpret_mode
 from .kernel import rms_norm_pallas
 
 __all__ = ["rms_norm_fused"]
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 @partial(jax.jit, static_argnames=("eps", "block_rows"))
 def rms_norm_fused(x, scale, eps: float = 1e-6, block_rows: int = 128):
     return rms_norm_pallas(x, scale, eps=eps, block_rows=block_rows,
-                           interpret=not _on_tpu())
+                           interpret=interpret_mode())

@@ -33,27 +33,30 @@ def ssd_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[...].astype(jnp.float32)            # [Q, Hb, P]
-    dt = dt_ref[...].astype(jnp.float32)          # [Q, Hb]
-    A = a_ref[...].astype(jnp.float32)            # [Hb]
+    xt = x_ref[...].astype(jnp.float32)           # [Hb, Q, P]
+    dtt = dt_ref[...].astype(jnp.float32)         # [Hb, Q]
+    A = a_ref[...].astype(jnp.float32)            # [Hb, 1]
     Bm = b_ref[...].astype(jnp.float32)           # [Q, N]
     Cm = c_ref[...].astype(jnp.float32)           # [Q, N]
     h = state_ref[...]                            # [Hb, P, N] fp32
 
-    Q, Hb, P = x.shape
-    xt = x.transpose(1, 0, 2)                     # [Hb, Q, P]
-    dtt = dt.T                                    # [Hb, Q]
+    Hb, Q, P = xt.shape
 
-    dA = dtt * A[:, None]                         # [Hb, Q]  (<= 0)
-    cum = jnp.cumsum(dA, axis=1)                  # [Hb, Q]
-    tot = cum[:, -1]                              # [Hb]
+    dA = dtt * A                                  # [Hb, Q]  (<= 0)
+    qi = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    ki = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    # inclusive prefix sum over the chunk as one MXU matmul with an upper
+    # triangle of ones (Mosaic has no cumsum lowering)
+    cum = jax.lax.dot_general(
+        dA, (qi <= ki).astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)       # [Hb, Q]
+    tot = jnp.sum(dA, axis=1, keepdims=True)      # [Hb, 1]
 
     # ---- intra-chunk quadratic term ----
     CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [Q, Q]
     diff = cum[:, :, None] - cum[:, None, :]      # [Hb, Q, Q]
-    qi = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    ki = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     L = jnp.where((qi >= ki)[None], jnp.exp(diff), 0.0)
     G = CB[None] * L * dtt[:, None, :]            # [Hb, Qq, Qk]
     y_intra = jax.lax.dot_general(
@@ -68,17 +71,16 @@ def ssd_scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
         preferred_element_type=jnp.float32)       # [Hb, Q, P]
     y_inter = Ch * jnp.exp(cum)[:, :, None]
 
-    y = (y_intra + y_inter).transpose(1, 0, 2)    # [Q, Hb, P]
-    y_ref[...] = y.astype(y_ref.dtype)
+    y_ref[...] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # ---- state update ----
-    w = (dtt * jnp.exp(tot[:, None] - cum))       # [Hb, Q]
+    w = dtt * jnp.exp(tot - cum)                  # [Hb, Q]
     xw = xt * w[:, :, None]                       # [Hb, Q, P]
     dstate = jax.lax.dot_general(
         xw, jnp.broadcast_to(Bm[None], (Hb, Q, Bm.shape[1])),
         (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)       # [Hb, P, N]
-    state_ref[...] = h * jnp.exp(tot)[:, None, None] + dstate
+    state_ref[...] = h * jnp.exp(tot)[:, :, None] + dstate
 
 
 def ssd_scan_pallas(xh: jax.Array, dt: jax.Array, A: jax.Array,
@@ -96,21 +98,25 @@ def ssd_scan_pallas(xh: jax.Array, dt: jax.Array, A: jax.Array,
     grid = (B, H // head_block, S // chunk)
 
     kernel = functools.partial(ssd_scan_kernel, chunk=chunk)
+    # head-major layouts: every block's last two dims are (rows, lanes) the
+    # TPU tiles — (chunk, P), (head_block, chunk), (head_block, 1) — instead
+    # of a head_block-wide lane dim the compiler refuses
     y = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, chunk, head_block, P),
-                         lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((None, chunk, head_block), lambda b, h, j: (b, j, h)),
-            pl.BlockSpec((head_block,), lambda b, h, j: (h,)),
+            pl.BlockSpec((None, head_block, chunk, P),
+                         lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((None, head_block, chunk), lambda b, h, j: (b, h, j)),
+            pl.BlockSpec((head_block, 1), lambda b, h, j: (h, 0)),
             pl.BlockSpec((None, chunk, N), lambda b, h, j: (b, j, 0)),
             pl.BlockSpec((None, chunk, N), lambda b, h, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((None, chunk, head_block, P),
-                               lambda b, h, j: (b, j, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, P), xh.dtype),
+        out_specs=pl.BlockSpec((None, head_block, chunk, P),
+                               lambda b, h, j: (b, h, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, P), xh.dtype),
         scratch_shapes=[pltpu.VMEM((head_block, P, N), jnp.float32)],
         interpret=interpret,
-    )(xh, dt, A, Bc, Cc)
-    return y
+    )(xh.transpose(0, 2, 1, 3), dt.transpose(0, 2, 1), A.reshape(H, 1),
+      Bc, Cc)
+    return y.transpose(0, 2, 1, 3)

@@ -6,21 +6,15 @@ from typing import Optional, Tuple
 
 import jax
 
+from .. import interpret_mode
 from .kernel import ssd_scan_pallas
 
 __all__ = ["ssd_scan"]
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
 
 
 @partial(jax.jit, static_argnames=("chunk", "head_block"))
 def ssd_scan(xh, dt, A, Bc, Cc, chunk: int = 128, head_block: int = 0
              ) -> Tuple[jax.Array, Optional[jax.Array]]:
     y = ssd_scan_pallas(xh, dt, A, Bc, Cc, chunk=chunk,
-                        head_block=head_block, interpret=not _on_tpu())
+                        head_block=head_block, interpret=interpret_mode())
     return y, None

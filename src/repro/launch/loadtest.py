@@ -118,6 +118,8 @@ def main(argv=None) -> int:
                          "persistent metrics store (default root: "
                          "results/metrics, or REPRO_METRICS_DIR)")
     args = ap.parse_args(argv)
+    from .mesh import enable_compile_cache
+    enable_compile_cache()
 
     if args.quick:
         args.requests = min(args.requests, 16)

@@ -17,28 +17,22 @@ merges by) and, when a trace path is given, attaches a
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Optional, Tuple
 
 import jax
 
 __all__ = ["make_production_mesh", "make_mesh", "fleet_session",
-           "SINGLE_POD", "MULTI_POD"]
+           "enable_compile_cache", "SINGLE_POD", "MULTI_POD"]
 
 SINGLE_POD = (16, 16)
 MULTI_POD = (2, 16, 16)
 
 
 def _make(shape, axes):
-    # jax < 0.5 has neither sharding.AxisType nor make_mesh(axis_types=...);
-    # Auto is that older default, so plain make_mesh is equivalent there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -68,3 +62,22 @@ def fleet_session(name: str, trace_path: Optional[str] = None
     path = shard_path(trace_path) if trace_path else None
     return TraceSession(name=name, jsonl_path=path,
                         tags=process_tags()), path
+
+
+#: Fixed persistent-cache location: a directory named from a temporary path,
+#: a process id or the time would start every run with an empty cache.
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, already configures the cache
+    and is left alone.  Otherwise the cache lives in ``<repo>/.jax_cache``.
+    Call before the first compile: JAX fixes the cache on first use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(REPO_COMPILE_CACHE)

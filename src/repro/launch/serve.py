@@ -25,7 +25,7 @@ import numpy as np
 from ..configs import ARCHS, SMOKE_ARCHS
 from ..runtime.server import ContinuousBatchingServer, Request, Server
 from ..tune.policy import load_policy_for
-from .mesh import fleet_session
+from .mesh import enable_compile_cache, fleet_session
 
 
 def main() -> None:
@@ -64,6 +64,7 @@ def main() -> None:
     ap.add_argument("--profile", action="store_true",
                     help="print per-span command attribution after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (SMOKE_ARCHS if args.smoke else ARCHS)[args.arch]
     tpl = args.tokens_per_launch

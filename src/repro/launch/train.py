@@ -21,7 +21,7 @@ from ..configs import ARCHS, SMOKE_ARCHS
 from ..configs.shapes import ShapeConfig
 from ..runtime.trainer import Trainer
 from ..tune.policy import load_policy_for
-from .mesh import fleet_session
+from .mesh import enable_compile_cache, fleet_session
 
 
 def main() -> None:
@@ -47,6 +47,7 @@ def main() -> None:
     ap.add_argument("--profile", action="store_true",
                     help="print per-span command attribution after the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (SMOKE_ARCHS if args.smoke else ARCHS)[args.arch]
     shape = ShapeConfig("train", args.seq, args.batch, "train")

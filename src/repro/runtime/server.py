@@ -77,7 +77,9 @@ class Server:
         # trainer's or a benchmark's; otherwise the server owns one.
         self.session = session or TraceSession(name="server")
         self.tracker = self.session.doorbell
-        self.params = self.model.init_params(jax.random.PRNGKey(seed))
+        # jitted: the compiled init writes bf16 weights directly, where an
+        # eager init holds a float32 temporary per weight
+        self.params = jax.jit(self.model.init_params)(jax.random.PRNGKey(seed))
 
         self._prefill = self.tracker.wrap(
             jax.jit(lambda p, toks: self.model.prefill(p, toks, max_seq)),

@@ -84,3 +84,23 @@ def test_pp_decoder_schedule_math():
             t = (m + s) % n_micro
             assert (t - s) % n_micro == m
             assert (t < s) == (m + s >= n_micro)   # the pos_tok offset rule
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise <repo>/.jax_cache,
+    a fixed path that git ignores."""
+    from repro.launch.mesh import REPO_COMPILE_CACHE, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO_COMPILE_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_COMPILE_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = REPO_COMPILE_CACHE.parent
+    assert (root / "src" / "repro" / "launch" / "mesh.py").is_file()
+    with open(root / ".gitignore") as f:
+        assert ".jax_cache/" in f.read().split()
