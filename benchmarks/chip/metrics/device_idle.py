@@ -1,0 +1,5 @@
+"""Share of the traced slice, over steps with at least one slot active, in
+which no operation ran on the device, in %."""
+import readers
+
+read = readers.device_idle
