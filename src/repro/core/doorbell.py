@@ -14,20 +14,31 @@ submission accounting.
 
 Every recorded cycle is also published as a ``dispatch`` event on the bound
 or ambient :class:`~repro.core.session.TraceSession` (see that module);
-standalone use without a session is unchanged.
+standalone use without a session is unchanged.  A wrapped call also runs
+inside a ``jax.profiler.TraceAnnotation`` named ``dispatch.<name>``, so a
+profiler trace shows each launch's host side beside the device's programs.
+
+A tracker may live as long as a long-running server, so it keeps only the
+newest :data:`MAX_RECORDS` records; its counts and totals are running sums
+over every cycle.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, Optional
 
 import jax
 
 from .session import TraceSession, resolve_session
 
-__all__ = ["DoorbellRecord", "DoorbellTracker", "payload_bytes"]
+__all__ = ["DoorbellRecord", "DoorbellTracker", "payload_bytes",
+           "MAX_RECORDS"]
+
+#: How many of the newest records a tracker keeps.
+MAX_RECORDS = 4096
 
 
 def payload_bytes(tree: Any) -> int:
@@ -59,9 +70,12 @@ class DoorbellTracker:
     """Counts and times submission cycles ("doorbell writes")."""
 
     def __init__(self, session: Optional[TraceSession] = None) -> None:
-        self.records: List[DoorbellRecord] = []
+        self.records: Deque[DoorbellRecord] = collections.deque(
+            maxlen=MAX_RECORDS)
         self._seq = 0
         self._session = session
+        # per name, over every cycle: doorbells, dispatch_s, payload_bytes
+        self._by_name: Dict[str, Dict[str, float]] = {}
 
     # -- wrapping ----------------------------------------------------------
     def wrap(self, fn: Callable, name: str = "dispatch",
@@ -73,15 +87,18 @@ class DoorbellTracker:
         the analogue of the doorbell write returning immediately while the
         GPU consumes the GPFIFO.
         """
+        label = f"dispatch.{name}"
+
         @functools.wraps(fn)
         def wrapped(*args, **kwargs):
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            t1 = time.perf_counter()
-            complete = 0.0
-            if block:
-                jax.block_until_ready(out)
-                complete = time.perf_counter() - t0
+            with jax.profiler.TraceAnnotation(label):
+                t0 = time.perf_counter()
+                out = fn(*args, **kwargs)
+                t1 = time.perf_counter()
+                complete = 0.0
+                if block:
+                    jax.block_until_ready(out)
+                    complete = time.perf_counter() - t0
             self._record(name, t0, t1 - t0, complete,
                          payload_bytes((args, kwargs)))
             return out
@@ -98,37 +115,39 @@ class DoorbellTracker:
             seq=self._seq, name=name, t_submit=t0, dispatch_s=disp,
             complete_s=comp, payload_bytes=payload))
         self._seq += 1
+        d = self._by_name.setdefault(name, {"doorbells": 0, "dispatch_s": 0.0,
+                                            "payload_bytes": 0})
+        d["doorbells"] += 1
+        d["dispatch_s"] += disp
+        d["payload_bytes"] += payload
         sess = resolve_session(self._session)
         if sess is not None:
             sess.emit("dispatch", name, dur_s=disp, complete_s=comp,
                       payload_bytes=payload, t=t0)
 
-    # -- accounting --------------------------------------------------------
+    # -- accounting (over every cycle, not only the records kept) ----------
     @property
     def count(self) -> int:
-        return len(self.records)
+        return self._seq
 
     def count_for(self, name: str) -> int:
-        return sum(1 for r in self.records if r.name == name)
+        return self._total("doorbells", name)
 
     def total_dispatch_s(self, name: Optional[str] = None) -> float:
-        return sum(r.dispatch_s for r in self.records
-                   if name is None or r.name == name)
+        return self._total("dispatch_s", name)
 
     def total_payload(self, name: Optional[str] = None) -> int:
-        return sum(r.payload_bytes for r in self.records
-                   if name is None or r.name == name)
+        return self._total("payload_bytes", name)
+
+    def _total(self, key: str, name: Optional[str]):
+        return sum(d[key] for n, d in self._by_name.items()
+                   if name is None or n == name)
 
     def reset(self) -> None:
         self.records.clear()
         self._seq = 0
+        self._by_name.clear()
 
     def summary(self) -> Dict[str, Any]:
-        by_name: Dict[str, Dict[str, float]] = {}
-        for r in self.records:
-            d = by_name.setdefault(r.name, {"doorbells": 0, "dispatch_s": 0.0,
-                                            "payload_bytes": 0})
-            d["doorbells"] += 1
-            d["dispatch_s"] += r.dispatch_s
-            d["payload_bytes"] += r.payload_bytes
-        return {"total_doorbells": self.count, "by_name": by_name}
+        return {"total_doorbells": self.count,
+                "by_name": {n: dict(d) for n, d in self._by_name.items()}}

@@ -40,7 +40,9 @@ is stamped with the span's identity (``span_id``/``parent_span_id``/
 tied back to the API call that caused it.  Closing a span emits an
 :data:`SPAN_EVENT` close event; :mod:`repro.obs.profile` turns those into
 per-span command-attribution profiles and :mod:`repro.obs.export` renders
-them as nested Perfetto duration events.
+them as nested Perfetto duration events.  Scoped spans are also entered as
+``jax.profiler.TraceAnnotation``s of the same name, so a profiler trace
+shows them on its host plane, on the clock of the device planes.
 
 :meth:`TraceSession.report` renders the Listing-1-style interleaved timeline;
 :meth:`TraceSession.summary` gives JSON-serializable per-kind accounting.
@@ -57,6 +59,8 @@ import time
 import warnings
 from typing import (Any, Callable, Dict, IO, Iterable, Iterator, List,
                     Optional, Tuple)
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "EVENT_KINDS",
@@ -518,7 +522,8 @@ class TraceSession:
         ``parent`` defaults to the caller's current ambient span, so manual
         spans still slot into the causal tree.  Use :meth:`span` for the
         common lexically-scoped case — manual handles are for spans whose
-        lifetime crosses scheduler iterations (a serve request).
+        lifetime crosses scheduler iterations (a serve request), and are
+        not mirrored onto the profiler's trace.
         """
         if parent is None:
             parent = self._span_var.get()
@@ -549,12 +554,18 @@ class TraceSession:
         Exiting emits the ``obs.span`` close event (``t`` = span start,
         ``dur_s`` = span wall time) carrying ``attrs``.  Contextvar scoping
         makes concurrent threads' spans invisible to each other.
+
+        The span is also a ``jax.profiler.TraceAnnotation`` named ``name``
+        (without ``attrs``, so that with no profiler running it costs an
+        inactive TraceMe): a profiler trace puts each scoped span on the
+        same timeline as the device's programs.
         """
         handle = self.start_span(name, **attrs)
         handle.scoped = True
         token = self._span_var.set(handle.frame)
         try:
-            yield handle
+            with TraceAnnotation(name):
+                yield handle
         finally:
             self._span_var.reset(token)
             handle.end()

@@ -221,7 +221,8 @@ class DenseKV(KVCacheManager):
         return self._complete(slot, p["state"], logits)
 
     def _complete(self, slot: int, state, logits) -> int:
-        tok0 = int(jnp.argmax(logits[0, -1, :]))
+        with self.engine.session.span("serve.prefill.readback"):
+            tok0 = int(jnp.argmax(logits[0, -1, :]))
         self._slots = self._install(self._slots, state, np.int32(slot))
         self._nxt = self._nxt.at[slot, 0, 0].set(tok0)
         del self._pending[slot]
@@ -231,7 +232,8 @@ class DenseKV(KVCacheManager):
     def decode(self) -> np.ndarray:
         self._slots, toks, self._nxt = self._decode_slots(
             self.engine.params, self._slots, self._nxt)
-        return np.asarray(toks)                      # [B, T] host sync
+        with self.engine.session.span("serve.decode.readback"):
+            return np.asarray(toks)                  # [B, T] host sync
 
     def release(self, slot: int) -> None:
         self._pending.pop(slot, None)
@@ -321,19 +323,24 @@ class PagedKV(KVCacheManager):
         S = engine.max_seq
         L, Hk, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
 
+        # The named scopes label the programs' ops in a profiler trace:
+        # the table gather, the model, the rows it wrote, the scatter back.
         def decode_paged(params, k_pool, v_pool, tables, lengths, nxt):
             def slot_fn(table, length, tok):
-                st = {"k": gather_block_table(k_pool, table),
-                      "v": gather_block_table(v_pool, table),
-                      "length": length}
-                st, toks, tok = decode_slot(params, st, tok)
+                with jax.named_scope("kv_gather"):
+                    st = {"k": gather_block_table(k_pool, table),
+                          "v": gather_block_table(v_pool, table),
+                          "length": length}
+                with jax.named_scope("decode_slot"):
+                    st, toks, tok = decode_slot(params, st, tok)
                 # rows written this launch; start clamps exactly like the
                 # dense dynamic_update_slice when a finishing slot overruns
-                start = jnp.minimum(length, S - T)
-                rk = jax.lax.dynamic_slice(
-                    st["k"], (0, 0, start, 0, 0), (L, 1, T, Hk, hd))[:, 0]
-                rv = jax.lax.dynamic_slice(
-                    st["v"], (0, 0, start, 0, 0), (L, 1, T, Hk, hd))[:, 0]
+                with jax.named_scope("kv_rows"):
+                    start = jnp.minimum(length, S - T)
+                    rk = jax.lax.dynamic_slice(
+                        st["k"], (0, 0, start, 0, 0), (L, 1, T, Hk, hd))[:, 0]
+                    rv = jax.lax.dynamic_slice(
+                        st["v"], (0, 0, start, 0, 0), (L, 1, T, Hk, hd))[:, 0]
                 return toks, tok, rk, rv
 
             toks, nxts, rows_k, rows_v = jax.vmap(slot_fn)(
@@ -347,25 +354,30 @@ class PagedKV(KVCacheManager):
                 vp = scatter_block_rows(vp, tbl, rows_v[b], start)
                 return kp, vp
 
-            k_pool, v_pool = jax.lax.fori_loop(
-                0, tables.shape[0], body, (k_pool, v_pool))
+            with jax.named_scope("kv_scatter"):
+                k_pool, v_pool = jax.lax.fori_loop(
+                    0, tables.shape[0], body, (k_pool, v_pool))
             return k_pool, v_pool, toks, nxts
 
         self._decode_slots = engine.tracker.wrap(
             jax.jit(decode_paged), "decode_slots")
 
         def extend_paged(params, k_pool, v_pool, table, start, tokens):
-            st = {"k": gather_block_table(k_pool, table),
-                  "v": gather_block_table(v_pool, table),
-                  "length": start}
-            st, logits = model.prefill_extend(params, st, tokens)
+            with jax.named_scope("kv_gather"):
+                st = {"k": gather_block_table(k_pool, table),
+                      "v": gather_block_table(v_pool, table),
+                      "length": start}
+            with jax.named_scope("extend"):
+                st, logits = model.prefill_extend(params, st, tokens)
             C = tokens.shape[1]
-            rk = jax.lax.dynamic_slice(
-                st["k"], (0, 0, start, 0, 0), (L, 1, C, Hk, hd))[:, 0]
-            rv = jax.lax.dynamic_slice(
-                st["v"], (0, 0, start, 0, 0), (L, 1, C, Hk, hd))[:, 0]
-            k_pool = scatter_block_rows(k_pool, table, rk, start)
-            v_pool = scatter_block_rows(v_pool, table, rv, start)
+            with jax.named_scope("kv_rows"):
+                rk = jax.lax.dynamic_slice(
+                    st["k"], (0, 0, start, 0, 0), (L, 1, C, Hk, hd))[:, 0]
+                rv = jax.lax.dynamic_slice(
+                    st["v"], (0, 0, start, 0, 0), (L, 1, C, Hk, hd))[:, 0]
+            with jax.named_scope("kv_scatter"):
+                k_pool = scatter_block_rows(k_pool, table, rk, start)
+                v_pool = scatter_block_rows(v_pool, table, rv, start)
             return k_pool, v_pool, logits
 
         self._extend = engine.tracker.wrap(
@@ -502,7 +514,8 @@ class PagedKV(KVCacheManager):
                     (eng.max_seq - eng.T) // self.pt)
         for i in range(n_reg):
             self._register(int(self.tables[slot, i]), chain[i])
-        tok0 = int(jnp.argmax(logits[0, -1, :]))
+        with eng.session.span("serve.prefill.readback"):
+            tok0 = int(jnp.argmax(logits[0, -1, :]))
         self.lengths[slot] = plen
         self.ready[slot] = True
         self._nxt = self._nxt.at[slot, 0, 0].set(tok0)
@@ -548,13 +561,18 @@ class PagedKV(KVCacheManager):
     def decode(self) -> np.ndarray:
         # still-prefilling slots decode as empty scratch slots: their block
         # tables and lengths are masked so the launch never touches their
-        # half-written pages
-        tables = np.where(self.ready[:, None], self.tables, 0)
-        lengths = np.where(self.ready, self.lengths, 0).astype(np.int32)
+        # half-written pages.  The dispatch between the two spans stays
+        # stamped with the caller's span (serve.decode_iter).
+        sess = self.engine.session
+        with sess.span("serve.decode.upload"):
+            tables = jnp.asarray(np.where(self.ready[:, None], self.tables, 0))
+            lengths = jnp.asarray(
+                np.where(self.ready, self.lengths, 0).astype(np.int32))
         self.k_pool, self.v_pool, toks, self._nxt = self._decode_slots(
-            self.engine.params, self.k_pool, self.v_pool,
-            jnp.asarray(tables), jnp.asarray(lengths), self._nxt)
-        blocks = np.asarray(toks)                    # [B, T] host sync
+            self.engine.params, self.k_pool, self.v_pool, tables, lengths,
+            self._nxt)
+        with sess.span("serve.decode.readback"):
+            blocks = np.asarray(toks)                # [B, T] host sync
         self.lengths[self.ready] += self.engine.T
         return blocks
 

@@ -385,41 +385,46 @@ class ContinuousBatchingServer(Server):
         them one bounded launch per scheduler iteration so active slots
         keep decoding underneath.
         """
-        admitted = 0
-        for slot in self._free_slots():
-            tix = self.queue.pop(self.sched_policy)
-            if tix is None:
-                break
-            r = tix.request
-            if not self.kv.begin(slot, tix):
-                # page pool exhausted even after reclaiming shared pages
-                tix.status, tix.reason = "evicted", "kv_pages"
-                tix.t_done = time.perf_counter()
-                self.session.emit("progress", "serve.evict", uid=tix.uid,
-                                  reason=tix.reason)
-                self._end_request_span(tix)
-                self.sched_policy.note_finished(tix)
-                continue
-            tix.status, tix.slot = "active", slot
-            tix.t_admit = time.perf_counter()
-            # KV capacity: decode token j (0-based; token 0 comes straight
-            # from prefill logits) writes cache position prompt_len + j - 1,
-            # which must stay below max_seq.
-            tix.cap = self.max_seq - len(r.prompt) + 1
-            self._slot_tix[slot] = tix
-            self._prefilling.add(slot)
-            chunk = self.kv.chunk
-            if not (chunk and len(r.prompt) > chunk):
-                tok0 = self.kv.prefill_step(slot)   # one whole-prompt launch
-                self.session.emit("progress", "serve.admit", uid=tix.uid,
-                                  slot=slot,
-                                  queued_s=tix.t_admit - tix.t_submit)
-                self._on_first_token(tix, tok0)
-            else:
-                self.session.emit("progress", "serve.admit", uid=tix.uid,
-                                  slot=slot,
-                                  queued_s=tix.t_admit - tix.t_submit)
-            admitted += 1
+        free = self._free_slots()
+        if not free or len(self.queue) == 0:
+            return 0            # nothing to admit: no span either
+        with self.session.span("serve.admit") as span:
+            admitted = 0
+            for slot in free:
+                tix = self.queue.pop(self.sched_policy)
+                if tix is None:
+                    break
+                r = tix.request
+                if not self.kv.begin(slot, tix):
+                    # page pool exhausted even after reclaiming shared pages
+                    tix.status, tix.reason = "evicted", "kv_pages"
+                    tix.t_done = time.perf_counter()
+                    self.session.emit("progress", "serve.evict", uid=tix.uid,
+                                      reason=tix.reason)
+                    self._end_request_span(tix)
+                    self.sched_policy.note_finished(tix)
+                    continue
+                tix.status, tix.slot = "active", slot
+                tix.t_admit = time.perf_counter()
+                # KV capacity: decode token j (0-based; token 0 comes
+                # straight from prefill logits) writes cache position
+                # prompt_len + j - 1, which must stay below max_seq.
+                tix.cap = self.max_seq - len(r.prompt) + 1
+                self._slot_tix[slot] = tix
+                self._prefilling.add(slot)
+                chunk = self.kv.chunk
+                if not (chunk and len(r.prompt) > chunk):
+                    tok0 = self.kv.prefill_step(slot)   # whole-prompt launch
+                    self.session.emit("progress", "serve.admit", uid=tix.uid,
+                                      slot=slot,
+                                      queued_s=tix.t_admit - tix.t_submit)
+                    self._on_first_token(tix, tok0)
+                else:
+                    self.session.emit("progress", "serve.admit", uid=tix.uid,
+                                      slot=slot,
+                                      queued_s=tix.t_admit - tix.t_submit)
+                admitted += 1
+            span.attrs["admitted"] = admitted
         return admitted
 
     def _prefill_tick(self) -> None:
@@ -463,13 +468,28 @@ class ContinuousBatchingServer(Server):
     def step(self) -> bool:
         """One scheduler iteration: admit, advance one chunked prefill,
         then one decode launch across all decodable slots; harvest per-slot
-        tokens.  Returns False if idle."""
-        self._admit()
+        tokens.  Returns False if idle.
+
+        The iteration is the ``serve.step`` span, whose attrs say how many
+        slots were ``active`` at its decode (or at its end, without one),
+        how many requests it ``admitted`` and how many slots it
+        ``decoded``."""
+        with self.session.span("serve.step") as span:
+            admitted, active, decoded = self._step()
+            span.attrs.update(active=active, admitted=admitted,
+                              decoded=decoded)
+            # a step with only prefills pending still counts as busy
+            return decoded > 0 or self.n_active > 0
+
+    def _step(self) -> Tuple[int, int, int]:
+        """The body of :meth:`step`: (requests admitted, slots active,
+        slots decoded)."""
+        admitted = self._admit()
         self._prefill_tick()
         decodable = [slot for slot, tix in enumerate(self._slot_tix)
                      if tix is not None and slot not in self._prefilling]
         if not decodable:
-            return self.n_active > 0    # prefills pending still count
+            return admitted, self.n_active, 0
         # paged backend: grow block tables for the coming T writes; slots
         # the pool cannot serve are evicted (reason="kv_pages") and their
         # freed pages immediately retried for the survivors
@@ -481,8 +501,9 @@ class ContinuousBatchingServer(Server):
                 self._finish(self._slot_tix[slot], reason="kv_pages")
                 decodable.remove(slot)
             if not decodable:
-                return self.n_active > 0
-        with self.session.span("serve.decode_iter", active=self.n_active):
+                return admitted, self.n_active, 0
+        active = self.n_active
+        with self.session.span("serve.decode_iter", active=active):
             blocks = self.kv.decode()               # [B, T] host sync
             for slot in decodable:
                 tix = self._slot_tix[slot]
@@ -492,7 +513,7 @@ class ContinuousBatchingServer(Server):
                 tix.tokens.extend(int(t) for t in blocks[slot, :take])
                 if len(tix.tokens) >= budget:
                     self._finish(tix)
-        return True
+        return admitted, active, len(decodable)
 
     def run(self, idle_timeout_s: float = 5.0,
             poll_s: float = 0.0005) -> Dict[str, Any]:

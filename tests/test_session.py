@@ -146,6 +146,23 @@ def test_doorbell_standalone_records_identically():
     assert bare.count == inside.count == 2
 
 
+def test_doorbell_counts_stay_exact_past_the_record_cap():
+    from repro.core.doorbell import MAX_RECORDS
+    t = DoorbellTracker()
+    for _ in range(MAX_RECORDS + 10):
+        t.ring("a")
+    for _ in range(5):
+        t.ring("b", payload=3)
+    assert len(t.records) == MAX_RECORDS
+    assert t.records[-1].seq == MAX_RECORDS + 14
+    assert t.count == MAX_RECORDS + 15
+    assert (t.count_for("a"), t.count_for("b")) == (MAX_RECORDS + 10, 5)
+    assert (t.total_payload(), t.total_payload("b")) == (15, 15)
+    assert t.summary()["by_name"]["a"]["doorbells"] == MAX_RECORDS + 10
+    t.reset()
+    assert t.count == t.count_for("a") == 0 and not t.records
+
+
 def test_capture_standalone_records_identically():
     def run_one():
         cap = CommandStreamCapture()
