@@ -15,7 +15,8 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from .layers import Params, apply_rope, init_rms_norm, rms_norm, rotary
 
-__all__ = ["init_attention", "attention", "decode_attention", "init_kv_cache",
+__all__ = ["init_attention", "attention", "decode_attention",
+           "decode_attention_rows", "init_kv_cache",
            "chunked_causal_attention", "dense_causal_attention",
            "extend_attention", "gather_block_table", "scatter_block_rows"]
 
@@ -246,6 +247,41 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype,
     }
 
 
+def _decode_qkv(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array):
+    """The query and the new K/V row of one token at position ``pos``."""
+    hd = cfg.hd
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+    v_new = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
+    if cfg.qk_norm:
+        q = rms_norm(p["q_norm"], q)
+        k_new = rms_norm(p["k_norm"], k_new)
+    if cfg.pos_embed == "rope":
+        sin, cos = rotary(pos[None, None], hd, cfg.rope_theta)
+        q = apply_rope(q, sin, cos)
+        k_new = apply_rope(k_new, sin, cos)
+    return q, k_new, v_new
+
+
+def _decode_attend(p: Params, cfg: ModelConfig, q: jax.Array,
+                   k_cache: jax.Array, v_cache: jax.Array,
+                   valid: jax.Array) -> jax.Array:
+    """One query row against a whole [B, S_max, Hkv, hd] cache, positions
+    where ``valid`` [S_max] is false masked to exactly zero weight."""
+    B = q.shape[0]
+    h, hk, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.hd
+    n_rep = h // hk
+    scale = hd ** -0.5
+    q_ = q.reshape(B, hk, n_rep, hd)
+    scores = jnp.einsum("bgrd,bsgd->bgrs", q_, k_cache).astype(jnp.float32) * scale
+    scores = jnp.where(valid[None, None, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bgrs,bsgd->bgrd", probs.astype(v_cache.dtype), v_cache)
+    out = out.reshape(B, 1, h, hd)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+
+
 def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
                      k_cache: jax.Array, v_cache: jax.Array,
                      length: jax.Array,
@@ -256,35 +292,49 @@ def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
     number of valid cache positions (the new token is written at ``length``).
     Returns (out [B,1,D], new_k_cache, new_v_cache).
     """
-    B, _, D = x.shape
-    h, hk, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.hd
     S = k_cache.shape[1]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
-    v_new = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
-    if cfg.qk_norm:
-        q = rms_norm(p["q_norm"], q)
-        k_new = rms_norm(p["k_norm"], k_new)
-    if cfg.pos_embed == "rope":
-        pos = length[None, None]
-        sin, cos = rotary(pos, hd, cfg.rope_theta)
-        q = apply_rope(q, sin, cos)
-        k_new = apply_rope(k_new, sin, cos)
+    q, k_new, v_new = _decode_qkv(p, cfg, x, length)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new.astype(k_cache.dtype), (0, length, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(
         v_cache, v_new.astype(v_cache.dtype), (0, length, 0, 0))
-    n_rep = h // hk
-    scale = hd ** -0.5
     # scores against the whole cache; invalid positions masked by length
-    q_ = q.reshape(B, hk, n_rep, hd)
-    scores = jnp.einsum("bgrd,bsgd->bgrs", q_, k_cache).astype(jnp.float32) * scale
-    valid = jnp.arange(S)[None, None, None, :] <= length
-    scores = jnp.where(valid, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bgrs,bsgd->bgrd", probs.astype(v_cache.dtype), v_cache)
-    out = out.reshape(B, 1, h, hd)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k_cache, v_cache
+    out = _decode_attend(p, cfg, q, k_cache, v_cache, jnp.arange(S) <= length)
+    return out, k_cache, v_cache
+
+
+def decode_attention_rows(p: Params, cfg: ModelConfig, x: jax.Array,
+                          k_cache: jax.Array, v_cache: jax.Array,
+                          rows_k: jax.Array, rows_v: jax.Array,
+                          start: jax.Array, pos: jax.Array,
+                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One-token attention against a read-only cache plus a row window.
+
+    x: [B, 1, D] at position ``pos``; k_cache/v_cache: [B, S_max, Hkv, hd],
+    read only; rows_k/rows_v: [B, T, Hkv, hd], the window standing for
+    cache positions start..start+T-1.  The new K/V row goes into the window
+    where :func:`decode_attention` would write the cache (``pos`` clamped
+    to S_max - 1).  The query then attends the cache with the window
+    selected in at its positions: the same S_max positions holding the
+    same rows as :func:`decode_attention`'s written cache, through the
+    same attention body, so both give the same bits, while the selection
+    feeds the attention and nothing of cache size is written.
+    Returns (out [B,1,D], rows_k, rows_v).
+    """
+    S, T = k_cache.shape[1], rows_k.shape[1]
+    q, k_new, v_new = _decode_qkv(p, cfg, x, pos)
+    w = jnp.minimum(pos, S - 1) - start
+    rows_k = jax.lax.dynamic_update_slice(
+        rows_k, k_new.astype(rows_k.dtype), (0, w, 0, 0))
+    rows_v = jax.lax.dynamic_update_slice(
+        rows_v, v_new.astype(rows_v.dtype), (0, w, 0, 0))
+    s = jnp.arange(S)
+    k, v = k_cache, v_cache
+    for j in range(T):          # one broadcast row each: no gather to write
+        here = (s == start + j)[None, :, None, None]
+        k = jnp.where(here, rows_k[:, j:j + 1], k)
+        v = jnp.where(here, rows_v[:, j:j + 1], v)
+    return _decode_attend(p, cfg, q, k, v, s <= pos), rows_k, rows_v
 
 
 def extend_attention(p: Params, cfg: ModelConfig, x: jax.Array,

@@ -12,8 +12,8 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import (attention, decode_attention, extend_attention,
-                        init_attention, init_kv_cache)
+from .attention import (attention, decode_attention, decode_attention_rows,
+                        extend_attention, init_attention, init_kv_cache)
 from .layers import (Params, cross_entropy_loss, dtype_of, embed,
                      init_embedding, init_mlp, init_rms_norm, mlp, rms_norm,
                      unembed)
@@ -51,18 +51,33 @@ def block_forward(p: Params, cfg: ModelConfig, x: jax.Array,
     return x + m, aux
 
 
-def block_decode(p: Params, cfg: ModelConfig, x: jax.Array,
-                 k_cache: jax.Array, v_cache: jax.Array, length: jax.Array
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    a, k_cache, v_cache = decode_attention(
-        p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache, length)
-    x = x + a
+def _serve_ffn(p: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
+    """The block's second half on the serving paths (no aux loss)."""
     h = rms_norm(p["ln2"], x)
     if cfg.n_experts:
         m, _ = moe_block(p["moe"], cfg, h)
     else:
         m = mlp(p["mlp"], h, cfg.act)
-    return x + m, k_cache, v_cache
+    return x + m
+
+
+def block_decode(p: Params, cfg: ModelConfig, x: jax.Array,
+                 k_cache: jax.Array, v_cache: jax.Array, length: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    a, k_cache, v_cache = decode_attention(
+        p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache, length)
+    return _serve_ffn(p, cfg, x + a), k_cache, v_cache
+
+
+def block_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
+                      k_cache: jax.Array, v_cache: jax.Array,
+                      rows_k: jax.Array, rows_v: jax.Array,
+                      start: jax.Array, pos: jax.Array
+                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    a, rows_k, rows_v = decode_attention_rows(
+        p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache,
+        rows_k, rows_v, start, pos)
+    return _serve_ffn(p, cfg, x + a), rows_k, rows_v
 
 
 def block_extend(p: Params, cfg: ModelConfig, x: jax.Array,
@@ -70,13 +85,7 @@ def block_extend(p: Params, cfg: ModelConfig, x: jax.Array,
                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     a, k_cache, v_cache = extend_attention(
         p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache, start)
-    x = x + a
-    h = rms_norm(p["ln2"], x)
-    if cfg.n_experts:
-        m, _ = moe_block(p["moe"], cfg, h)
-    else:
-        m = mlp(p["mlp"], h, cfg.act)
-    return x + m, k_cache, v_cache
+    return _serve_ffn(p, cfg, x + a), k_cache, v_cache
 
 
 class TransformerLM:
@@ -217,3 +226,47 @@ class TransformerLM:
         logits = unembed(params["emb"], x)
         new_state = {"k": new_k, "v": new_v, "length": length + 1}
         return new_state, logits
+
+    def decode_rows(self, params: Params, k_cache: jax.Array,
+                    v_cache: jax.Array, length: jax.Array, tok: jax.Array,
+                    T: int):
+        """T greedy tokens of one sequence against a read-only cache.
+
+        k_cache/v_cache: [L, 1, S, Hkv, hd] with ``length`` valid positions;
+        tok: [1, 1], the token at position ``length``.  The T token steps
+        carry only the token and a T-row window per layer, standing for
+        cache positions start..start+T-1 with start = min(length, S - T):
+        it ends holding exactly what T ``decode_step`` calls would leave
+        there, clamped final-position writes included, so writing it back
+        at ``start`` updates the cache as they would.  Each step attends
+        the cache with the window selected in, through the body
+        ``decode_step`` uses, so the tokens are the same bits.  Returns
+        (toks [T], nxt [1, 1], rows_k, rows_v [L, T, Hkv, hd]).
+        """
+        cfg = self.cfg
+        L, _, S, Hk, hd = k_cache.shape
+        start = jnp.minimum(length, S - T)
+
+        def window(c):
+            return jax.lax.dynamic_slice(c, (0, 0, start, 0, 0),
+                                         (L, 1, T, Hk, hd))
+
+        def step(carry, t):
+            tok, rk, rv = carry
+            x = embed(params["emb"], tok, cfg.embed_scale)
+
+            def layer(x, inp):
+                lp, kc, vc, rk_l, rv_l = inp
+                x, rk_l, rv_l = block_decode_rows(
+                    lp, cfg, x, kc, vc, rk_l, rv_l, start, length + t)
+                return x, (rk_l, rv_l)
+
+            x, (rk, rv) = jax.lax.scan(
+                layer, x, (params["layers"], k_cache, v_cache, rk, rv))
+            logits = unembed(params["emb"], rms_norm(params["final_norm"], x))
+            nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
+            return (nxt, rk, rv), nxt[0, 0]
+
+        (nxt, rk, rv), toks = jax.lax.scan(
+            step, (tok, window(k_cache), window(v_cache)), jnp.arange(T))
+        return toks, nxt, rk[:, 0], rv[:, 0]

@@ -5,10 +5,11 @@ slot a full ``max_seq`` dense cache and prefilled whole prompts in one
 launch, stalling the decode loop.  This module extracts that state handling
 behind :class:`KVCacheManager` with two backends:
 
-* :class:`DenseKV` — the original layout, verbatim: one stacked batch-1
+* :class:`DenseKV` — the original layout: one stacked batch-1
   decode-state pytree per slot, whole-prompt prefill, one vmapped
-  ``decode_slots`` launch.  The refactor is token-bit-identical to the
-  pre-refactor engine (same jitted functions, same launch order).
+  ``decode_slots`` launch, token-bit-identical to the pre-refactor
+  engine (a transformer's rows body attends what ``decode_step`` would,
+  through the same attention body).
 * :class:`PagedKV` — a single global page pool ``[L, pages, page_tokens,
   Hkv, hd]`` with per-slot block tables.  A decode launch gathers each
   slot's pages into the *same contiguous layout the dense path decodes*,
@@ -56,13 +57,33 @@ __all__ = ["KVCacheManager", "DenseKV", "PagedKV", "make_kv", "KV_BACKENDS"]
 KV_BACKENDS = ("dense", "paged")
 
 
-def _decode_slot_fn(model, T: int):
-    """The shared per-slot decode body: scan T greedy steps, one launch.
+def _decode_body(model) -> str:
+    """Which per-slot decode body a model gets: ``"rows"`` where it offers
+    ``decode_rows`` (the transformer family: the T token steps read the
+    cache and carry only the launch's new rows), else ``"carry"`` (the T
+    steps carry the whole decode state)."""
+    return "rows" if hasattr(model, "decode_rows") else "carry"
 
-    Both backends jit/vmap this exact function, which is what makes their
-    token streams bit-identical — the only difference is where the cache
-    lives before and after.
+
+def _decode_slot_fn(model, T: int):
+    """The dense per-slot decode body: T greedy steps, one launch.
+
+    With ``decode_rows`` it is the body the paged launch runs too, the
+    rows written back with one ``dynamic_update_slice``, whose start
+    clamps to ``S - T`` exactly as the paged scatter's does — which is
+    what makes the two backends' token streams bit-identical.
     """
+    if _decode_body(model) == "rows":
+        def decode_rows_slot(params, state, tok):
+            length = state["length"]
+            toks, nxt, rk, rv = model.decode_rows(
+                params, state["k"], state["v"], length, tok, T)
+            at = (0, 0, length, 0, 0)
+            k = jax.lax.dynamic_update_slice(state["k"], rk[:, None], at)
+            v = jax.lax.dynamic_update_slice(state["v"], rv[:, None], at)
+            return {"k": k, "v": v, "length": length + T}, toks, nxt
+
+        return decode_rows_slot
 
     def decode_slot(params, state, tok):             # state: batch-1 pytree
         def body(carry, _):
@@ -112,10 +133,12 @@ class KVCacheManager:
         raise NotImplementedError
 
 
-class _PrefillCounters:
+class _LaunchCounters:
     """Shared launch/byte accounting (feeds loadtest --json and BENCH)."""
 
-    def __init__(self) -> None:
+    def __init__(self, decode_body: str) -> None:
+        self.decode_body = decode_body   # body chosen at construction
+        self.decode_launches = 0
         self.prefill_launches = 0        # all prefill/extend launches
         self.prefill_chunk_launches = 0  # the subset that were chunk ticks
         self.prefill_tokens = 0          # prompt tokens actually pushed
@@ -125,8 +148,10 @@ class _PrefillCounters:
     def prefill_payload_bytes(self) -> int:
         return 4 * self.prefill_tokens
 
-    def to_dict(self) -> Dict[str, int]:
+    def to_dict(self) -> Dict[str, Any]:
         return {
+            "decode_body": self.decode_body,
+            "decode_launches": self.decode_launches,
             "prefill_launches": self.prefill_launches,
             "prefill_chunk_launches": self.prefill_chunk_launches,
             "prefill_tokens": self.prefill_tokens,
@@ -146,8 +171,9 @@ def _require_extend(model, why: str) -> None:
 class DenseKV(KVCacheManager):
     """Today's layout behind the manager interface (bit-identical refactor).
 
-    Slot state, install scatter, and the vmapped ``decode_slots`` launch are
-    the exact jitted functions the engine built inline before the refactor.
+    Slot state and install scatter are the jitted functions the engine
+    built inline before the refactor; the vmapped ``decode_slots`` launch
+    runs :func:`_decode_slot_fn`.
     Chunked prefill stages chunks in a private batch-1 state and installs it
     on completion, so the stacked slot states see exactly one update per
     admission either way.
@@ -178,7 +204,7 @@ class DenseKV(KVCacheManager):
             self._extend = engine.tracker.wrap(
                 jax.jit(engine.model.prefill_extend), "prefill_extend")
         self._pending: Dict[int, Dict[str, Any]] = {}
-        self.counters = _PrefillCounters()
+        self.counters = _LaunchCounters(_decode_body(engine.model))
 
     # -- prefill -----------------------------------------------------------
     def begin(self, slot: int, tix: "RequestTicket") -> bool:
@@ -232,6 +258,7 @@ class DenseKV(KVCacheManager):
     def decode(self) -> np.ndarray:
         self._slots, toks, self._nxt = self._decode_slots(
             self.engine.params, self._slots, self._nxt)
+        self.counters.decode_launches += 1
         with self.engine.session.span("serve.decode.readback"):
             return np.asarray(toks)                  # [B, T] host sync
 
@@ -311,36 +338,28 @@ class PagedKV(KVCacheManager):
         self._chain: Dict[int, List[bytes]] = {}      # slot -> page keys
         self._pending: Dict[int, Dict[str, Any]] = {}
 
-        self.counters = _PrefillCounters()
+        self.counters = _LaunchCounters("rows")
         self.pages_allocated = 0                      # cumulative fresh
         self.pages_reused = 0                         # prefix-hit attaches
         self.prefix_hits = 0                          # requests that hit
         self.prefix_hit_tokens = 0
         self.pages_peak = 0
 
-        decode_slot = _decode_slot_fn(model, engine.T)
-        T, n_blk, pt = engine.T, self.n_blk, self.pt
-        S = engine.max_seq
+        T, S = engine.T, engine.max_seq
         L, Hk, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
 
         # The named scopes label the programs' ops in a profiler trace:
-        # the table gather, the model, the rows it wrote, the scatter back.
+        # the table gather, the model, the scatter back.  The scatter's
+        # start clamps exactly like the dense dynamic_update_slice when a
+        # finishing slot overruns.
         def decode_paged(params, k_pool, v_pool, tables, lengths, nxt):
             def slot_fn(table, length, tok):
                 with jax.named_scope("kv_gather"):
-                    st = {"k": gather_block_table(k_pool, table),
-                          "v": gather_block_table(v_pool, table),
-                          "length": length}
+                    k = gather_block_table(k_pool, table)
+                    v = gather_block_table(v_pool, table)
                 with jax.named_scope("decode_slot"):
-                    st, toks, tok = decode_slot(params, st, tok)
-                # rows written this launch; start clamps exactly like the
-                # dense dynamic_update_slice when a finishing slot overruns
-                with jax.named_scope("kv_rows"):
-                    start = jnp.minimum(length, S - T)
-                    rk = jax.lax.dynamic_slice(
-                        st["k"], (0, 0, start, 0, 0), (L, 1, T, Hk, hd))[:, 0]
-                    rv = jax.lax.dynamic_slice(
-                        st["v"], (0, 0, start, 0, 0), (L, 1, T, Hk, hd))[:, 0]
+                    toks, tok, rk, rv = model.decode_rows(
+                        params, k, v, length, tok, T)
                 return toks, tok, rk, rv
 
             toks, nxts, rows_k, rows_v = jax.vmap(slot_fn)(
@@ -571,6 +590,7 @@ class PagedKV(KVCacheManager):
         self.k_pool, self.v_pool, toks, self._nxt = self._decode_slots(
             self.engine.params, self.k_pool, self.v_pool, tables, lengths,
             self._nxt)
+        self.counters.decode_launches += 1
         with sess.span("serve.decode.readback"):
             blocks = np.asarray(toks)                # [B, T] host sync
         self.lengths[self.ready] += self.engine.T
