@@ -143,6 +143,22 @@ class _LaunchCounters:
         self.prefill_chunk_launches = 0  # the subset that were chunk ticks
         self.prefill_tokens = 0          # prompt tokens actually pushed
         self.chunked_prompts = 0         # prompts that needed >1 launch
+        # query-key pairs of the extend launches: every one the attention
+        # scores (each query against the whole cache buffer) and those the
+        # causal mask keeps (each query against itself and what precedes it)
+        self.extend_scored_positions = 0
+        self.extend_live_positions = 0
+
+    def count_extend(self, start: int, size: int, buffer: int
+                     ) -> Dict[str, int]:
+        """Count one extend launch of ``size`` prompt positions from
+        ``start`` against a cache of ``buffer`` positions; returns the
+        launch's ``scored`` and ``live`` pairs, for its span."""
+        scored = size * buffer
+        live = size * start + size * (size + 1) // 2
+        self.extend_scored_positions += scored
+        self.extend_live_positions += live
+        return {"scored": scored, "live": live}
 
     @property
     def prefill_payload_bytes(self) -> int:
@@ -157,6 +173,8 @@ class _LaunchCounters:
             "prefill_tokens": self.prefill_tokens,
             "prefill_payload_bytes": self.prefill_payload_bytes,
             "chunked_prompts": self.chunked_prompts,
+            "extend_scored_positions": self.extend_scored_positions,
+            "extend_live_positions": self.extend_live_positions,
         }
 
 
@@ -233,8 +251,9 @@ class DenseKV(KVCacheManager):
             p["state"] = eng.model.init_decode_state(1, eng.max_seq)
         pos = p["pos"]
         c = min(self.chunk, len(prompt) - pos)
+        pairs = self.counters.count_extend(pos, c, eng.max_seq)
         with eng.session.span("serve.prefill_chunk", uid=tix.uid, start=pos,
-                              size=c, prompt_len=int(len(prompt))):
+                              size=c, prompt_len=int(len(prompt)), **pairs):
             p["state"], logits = self._extend(
                 eng.params, p["state"], jnp.asarray(prompt[None, pos:pos + c]))
         p["pos"] = pos + c
@@ -507,8 +526,9 @@ class PagedKV(KVCacheManager):
         c = min(self.chunk, remaining) if p["chunked"] else remaining
         span_name = "serve.prefill_chunk" if p["chunked"] else "serve.prefill"
         table = jnp.asarray(self.tables[slot])
+        pairs = self.counters.count_extend(pos, c, self.n_blk * self.pt)
         with eng.session.span(span_name, uid=tix.uid, start=pos, size=c,
-                              prompt_len=plen):
+                              prompt_len=plen, **pairs):
             self.k_pool, self.v_pool, logits = self._extend(
                 eng.params, self.k_pool, self.v_pool, table,
                 jnp.asarray(pos, jnp.int32), jnp.asarray(prompt[None,

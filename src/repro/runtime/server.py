@@ -431,9 +431,9 @@ class ContinuousBatchingServer(Server):
         """Advance at most ONE pending chunked prefill by one launch.
 
         One bounded launch per scheduler iteration keeps the decode-iter
-        gap under control (the acceptance bar: no gap beyond 2x the median
-        decode-iter duration); round-robin across prefilling slots keeps
-        long prompts from starving each other.
+        gap under control (the acceptance bar: no two chunk launches
+        without a decode iteration between them); round-robin across
+        prefilling slots keeps long prompts from starving each other.
         """
         pending = sorted(s for s in self._prefilling
                          if self._slot_tix[s] is not None)
