@@ -50,7 +50,8 @@ PAGE_TOKENS, PREFILL_CHUNK, PREFIX_LEN = 64, 256, 64
 # misindexed cache row or a bf16 leak (errors of 1e-2 and up) still fails.
 LOGIT_TOL = 2e-3
 # Phase 3: the per-dtype tolerances of tests/test_kernels.py.
-KERNEL_TOL = {"flash_attention": 3e-2, "ssd_scan": 6e-2, "rms_norm": 2e-2}
+KERNEL_TOL = {"flash_attention": 3e-2, "ssd_scan": 6e-2, "rms_norm": 2e-2,
+              "paged_attention": 3e-2}
 
 
 class SmokeFailure(RuntimeError):
@@ -230,6 +231,8 @@ def phase_kernels() -> None:
     from repro.kernels.dma_copy.ref import dma_copy_ref
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import flash_attention_ref
+    from repro.kernels.paged_attention.ops import paged_attention
+    from repro.kernels.paged_attention.ref import paged_attention_ref
     from repro.kernels.rms_norm.ops import rms_norm_fused
     from repro.kernels.rms_norm.ref import rms_norm_ref
     from repro.kernels.ssd_scan.ops import ssd_scan
@@ -261,6 +264,23 @@ def phase_kernels() -> None:
         q, k, v = (normal((1, 4096, 8, 256), jnp.bfloat16) for _ in range(3))
         compare("flash_attention", flash_attention(q, k, v, causal=True),
                 flash_attention_ref(q, k, v, causal=True))
+        # the paged decode launch's attention at Gemma-2B widths (MQA 8/1,
+        # hd 256) over SLOTS slots of MAX_SEQ in 64-token pages: read
+        # lengths from none to the whole table, pages shared between slots
+        n_blk = MAX_SEQ // 64
+        pool = (18, 1 + SLOTS * n_blk, 64, 1, 256)
+        kp, vp = normal(pool, jnp.bfloat16), normal(pool, jnp.bfloat16)
+        q = normal((SLOTS, 8, 256), jnp.bfloat16)
+        tables = jax.random.randint(next(keys), (SLOTS, n_blk), 0, pool[1])
+        n = jnp.asarray([0, 1, 63, 64, 700, 1400, 2000, MAX_SEQ], jnp.int32)
+        acc, m, l_sum = paged_attention(q, kp, vp, tables, n, jnp.int32(7))
+        r_acc, r_m, r_l = paged_attention_ref(q, kp, vp, tables, n, 7)
+        live = n > 0
+        compare("paged_attention",
+                jnp.concatenate([(acc / l_sum[..., None])[live].ravel(),
+                                 m[live].ravel()]),
+                jnp.concatenate([(r_acc / r_l[..., None])[live].ravel(),
+                                 r_m[live].ravel()]))
         # mamba2-780m SSD widths: H=48, P=64, N=128, chunk 256
         B, S, H, P, N = 1, 4096, 48, 64, 128
         xh = normal((B, S, H, P), jnp.bfloat16)

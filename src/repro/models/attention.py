@@ -16,7 +16,7 @@ from ..configs.base import ModelConfig
 from .layers import Params, apply_rope, init_rms_norm, rms_norm, rotary
 
 __all__ = ["init_attention", "attention", "decode_attention",
-           "decode_attention_rows", "init_kv_cache",
+           "decode_attention_rows", "decode_attention_paged", "init_kv_cache",
            "chunked_causal_attention", "dense_causal_attention",
            "extend_attention", "gather_block_table", "scatter_block_rows"]
 
@@ -248,7 +248,8 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype,
 
 
 def _decode_qkv(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array):
-    """The query and the new K/V row of one token at position ``pos``."""
+    """The query and the new K/V row of one token at position ``pos``: []
+    for every row of x [B, 1, D], or [B], one per row."""
     hd = cfg.hd
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k_new = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -257,7 +258,7 @@ def _decode_qkv(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array):
         q = rms_norm(p["q_norm"], q)
         k_new = rms_norm(p["k_norm"], k_new)
     if cfg.pos_embed == "rope":
-        sin, cos = rotary(pos[None, None], hd, cfg.rope_theta)
+        sin, cos = rotary(jnp.reshape(pos, (-1, 1)), hd, cfg.rope_theta)
         q = apply_rope(q, sin, cos)
         k_new = apply_rope(k_new, sin, cos)
     return q, k_new, v_new
@@ -335,6 +336,55 @@ def decode_attention_rows(p: Params, cfg: ModelConfig, x: jax.Array,
         k = jnp.where(here, rows_k[:, j:j + 1], k)
         v = jnp.where(here, rows_v[:, j:j + 1], v)
     return _decode_attend(p, cfg, q, k, v, s <= pos), rows_k, rows_v
+
+
+def decode_attention_paged(p: Params, cfg: ModelConfig, x: jax.Array,
+                           k_pool: jax.Array, v_pool: jax.Array,
+                           tables: jax.Array, layer: jax.Array,
+                           rows_k: jax.Array, rows_v: jax.Array,
+                           start: jax.Array, pos: jax.Array,
+                           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """One-token attention of every slot, reading the pages in place.
+
+    The paged form of :func:`decode_attention_rows` over all B slots at
+    once.  x: [B, 1, D], slot b at position ``pos[b]``; k_pool/v_pool:
+    [L, P, page_tokens, Hkv, hd], read only, of which ``layer`` is this
+    layer; tables: [B, n_blk]; rows_k/rows_v: [B, T, Hkv, hd], the window
+    standing for positions start..start+T-1 of each slot.  The new K/V row
+    goes into the window as there.  The query then attends two parts,
+    merged by log-sum-exp: positions [0, start) through the paged
+    attention kernel, which copies only those pages, and the window rows
+    up to ``min(pos, S - 1)``.  Scores and softmax are float32; a slot
+    with ``start == 0`` (empty, or table all scratch page) gets the window
+    alone.  Returns (out [B,1,D], rows_k, rows_v).
+    """
+    from ..kernels.paged_attention.ops import paged_attention
+    B, T = rows_k.shape[:2]
+    h, hk, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.hd
+    n_rep = h // hk
+    S = tables.shape[1] * k_pool.shape[2]
+    q, k_new, v_new = _decode_qkv(p, cfg, x, pos)
+    here = (jnp.arange(T)[None, :]
+            == (jnp.minimum(pos, S - 1) - start)[:, None])[:, :, None, None]
+    rows_k = jnp.where(here, k_new.astype(rows_k.dtype), rows_k)
+    rows_v = jnp.where(here, v_new.astype(rows_v.dtype), rows_v)
+    acc, m, l = paged_attention(q[:, 0], k_pool, v_pool, tables, start, layer)
+    acc = acc.reshape(B, hk, n_rep, hd)
+    m, l = m.reshape(B, hk, n_rep), l.reshape(B, hk, n_rep)
+    q_ = q.reshape(B, hk, n_rep, hd)
+    s = jnp.einsum("bgrd,btgd->bgrt", q_, rows_k,
+                   preferred_element_type=jnp.float32) * hd ** -0.5
+    live = (start[:, None] + jnp.arange(T)[None, :]) <= pos[:, None]
+    s = jnp.where(live[:, None, None, :], s, jnp.finfo(jnp.float32).min)
+    m_all = jnp.maximum(m, jnp.max(s, axis=-1))
+    alpha = jnp.exp(m - m_all)
+    pw = jnp.exp(s - m_all[..., None])
+    l_all = alpha * l + jnp.sum(pw, axis=-1)
+    acc = alpha[..., None] * acc + jnp.einsum(
+        "bgrt,btgd->bgrd", pw.astype(rows_v.dtype), rows_v,
+        preferred_element_type=jnp.float32)
+    out = (acc / l_all[..., None]).astype(rows_v.dtype).reshape(B, 1, h, hd)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), rows_k, rows_v
 
 
 def extend_attention(p: Params, cfg: ModelConfig, x: jax.Array,

@@ -12,8 +12,9 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import (attention, decode_attention, decode_attention_rows,
-                        extend_attention, init_attention, init_kv_cache)
+from .attention import (attention, decode_attention, decode_attention_paged,
+                        decode_attention_rows, extend_attention,
+                        init_attention, init_kv_cache)
 from .layers import (Params, cross_entropy_loss, dtype_of, embed,
                      init_embedding, init_mlp, init_rms_norm, mlp, rms_norm,
                      unembed)
@@ -77,6 +78,18 @@ def block_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
     a, rows_k, rows_v = decode_attention_rows(
         p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache,
         rows_k, rows_v, start, pos)
+    return _serve_ffn(p, cfg, x + a), rows_k, rows_v
+
+
+def block_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
+                       k_pool: jax.Array, v_pool: jax.Array,
+                       tables: jax.Array, layer: jax.Array,
+                       rows_k: jax.Array, rows_v: jax.Array,
+                       start: jax.Array, pos: jax.Array
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    a, rows_k, rows_v = decode_attention_paged(
+        p["attn"], cfg, rms_norm(p["ln1"], x), k_pool, v_pool, tables,
+        layer, rows_k, rows_v, start, pos)
     return _serve_ffn(p, cfg, x + a), rows_k, rows_v
 
 
@@ -270,3 +283,49 @@ class TransformerLM:
         (nxt, rk, rv), toks = jax.lax.scan(
             step, (tok, window(k_cache), window(v_cache)), jnp.arange(T))
         return toks, nxt, rk[:, 0], rv[:, 0]
+
+    def decode_rows_paged(self, params: Params, k_pool: jax.Array,
+                          v_pool: jax.Array, tables: jax.Array,
+                          lengths: jax.Array, tok: jax.Array, T: int):
+        """T greedy tokens of every slot, attending the pages in place.
+
+        The paged form of :meth:`decode_rows` over all B slots at once.
+        k_pool/v_pool: [L, P, page_tokens, Hkv, hd], read only; tables:
+        [B, n_blk] page ids; lengths: [B] valid positions; tok: [B, 1], the
+        token at position ``lengths``.  Each slot's window starts from the
+        pool's rows at start = min(length, S - T) (at most two pages) and
+        ends holding what :meth:`decode_rows` leaves in it.  The layer scan
+        passes the layer index and never scans over the pools, which the
+        paged attention kernel reads where they lie.  Returns (toks [B, T],
+        nxt [B, 1], rows_k, rows_v [L, B, T, Hkv, hd]).
+        """
+        cfg = self.cfg
+        L, _, pt = k_pool.shape[:3]
+        S = tables.shape[1] * pt
+        start = jnp.minimum(lengths, S - T)
+        at = start[:, None] + jnp.arange(T)[None, :]              # [B, T]
+        page = jnp.take_along_axis(tables, at // pt, axis=1)
+
+        def window(pool):
+            return pool[:, page, at % pt]                 # [L, B, T, Hk, hd]
+
+        def step(carry, t):
+            tok, rk, rv = carry
+            x = embed(params["emb"], tok, cfg.embed_scale)
+
+            def layer(x, inp):
+                lp, rk_l, rv_l, i = inp
+                x, rk_l, rv_l = block_decode_paged(
+                    lp, cfg, x, k_pool, v_pool, tables, i, rk_l, rv_l,
+                    start, lengths + t)
+                return x, (rk_l, rv_l)
+
+            x, (rk, rv) = jax.lax.scan(
+                layer, x, (params["layers"], rk, rv, jnp.arange(L)))
+            logits = unembed(params["emb"], rms_norm(params["final_norm"], x))
+            nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
+            return (nxt, rk, rv), nxt[:, 0]
+
+        (nxt, rk, rv), toks = jax.lax.scan(
+            step, (tok, window(k_pool), window(v_pool)), jnp.arange(T))
+        return toks.T, nxt, rk, rv

@@ -11,10 +11,13 @@ behind :class:`KVCacheManager` with two backends:
   engine (a transformer's rows body attends what ``decode_step`` would,
   through the same attention body).
 * :class:`PagedKV` — a single global page pool ``[L, pages, page_tokens,
-  Hkv, hd]`` with per-slot block tables.  A decode launch gathers each
-  slot's pages into the *same contiguous layout the dense path decodes*,
-  runs the identical decode math, and scatters the new rows back — so
-  paged tokens are bit-identical to dense.  Pages holding a fully-prefilled
+  Hkv, hd]`` with per-slot block tables.  On a TPU a decode launch attends
+  each slot's pages in place, up to its length, through the paged
+  attention kernel (``decode_body`` ``"pages"``).  Elsewhere it gathers
+  each slot's pages into the *same contiguous layout the dense path
+  decodes*, runs the identical decode math (``"rows"``) — so paged tokens
+  are bit-identical to dense there, the oracle of the kernel path.  Either
+  way the new rows are scattered back.  Pages holding a fully-prefilled
   prompt prefix are content-addressed (hash chain over page tokens) and
   shared across requests with the same prefix: a prefix hit skips those
   prefill tokens entirely, which is what makes the per-request command
@@ -63,6 +66,16 @@ def _decode_body(model) -> str:
     cache and carry only the launch's new rows), else ``"carry"`` (the T
     steps carry the whole decode state)."""
     return "rows" if hasattr(model, "decode_rows") else "carry"
+
+
+def _attends_pages_in_place(pool: jax.Array) -> bool:
+    """Whether a paged decode launch over ``pool`` reads the pages in place
+    through the paged attention kernel: on a TPU, where the kernel
+    compiles.  Elsewhere the launch gathers the block tables and runs
+    ``decode_rows``, the bit-identical twin of the dense path."""
+    if isinstance(pool, jax.core.Tracer):     # built abstractly
+        return jax.default_backend() == "tpu"
+    return all(d.platform == "tpu" for d in pool.devices())
 
 
 def _decode_slot_fn(model, T: int):
@@ -148,6 +161,10 @@ class _LaunchCounters:
         # causal mask keeps (each query against itself and what precedes it)
         self.extend_scored_positions = 0
         self.extend_live_positions = 0
+        # pages of the paged decode launches: those the attention reads
+        # and those the active slots' block tables hold
+        self.decode_pages_read = 0
+        self.decode_pages_table = 0
 
     def count_extend(self, start: int, size: int, buffer: int
                      ) -> Dict[str, int]:
@@ -175,6 +192,8 @@ class _LaunchCounters:
             "chunked_prompts": self.chunked_prompts,
             "extend_scored_positions": self.extend_scored_positions,
             "extend_live_positions": self.extend_live_positions,
+            "decode_pages_read": self.decode_pages_read,
+            "decode_pages_table": self.decode_pages_table,
         }
 
 
@@ -357,7 +376,8 @@ class PagedKV(KVCacheManager):
         self._chain: Dict[int, List[bytes]] = {}      # slot -> page keys
         self._pending: Dict[int, Dict[str, Any]] = {}
 
-        self.counters = _LaunchCounters("rows")
+        self.in_place = _attends_pages_in_place(self.k_pool)
+        self.counters = _LaunchCounters("pages" if self.in_place else "rows")
         self.pages_allocated = 0                      # cumulative fresh
         self.pages_reused = 0                         # prefix-hit attaches
         self.prefix_hits = 0                          # requests that hit
@@ -370,8 +390,8 @@ class PagedKV(KVCacheManager):
         # The named scopes label the programs' ops in a profiler trace:
         # the table gather, the model, the scatter back.  The scatter's
         # start clamps exactly like the dense dynamic_update_slice when a
-        # finishing slot overruns.
-        def decode_paged(params, k_pool, v_pool, tables, lengths, nxt):
+        # finishing slot overruns.  Rows come back [L, B, T, Hkv, hd].
+        def gathered_rows(params, k_pool, v_pool, tables, lengths, nxt):
             def slot_fn(table, length, tok):
                 with jax.named_scope("kv_gather"):
                     k = gather_block_table(k_pool, table)
@@ -381,15 +401,28 @@ class PagedKV(KVCacheManager):
                         params, k, v, length, tok, T)
                 return toks, tok, rk, rv
 
-            toks, nxts, rows_k, rows_v = jax.vmap(slot_fn)(
-                tables, lengths, nxt)
+            toks, nxts, rows_k, rows_v = jax.vmap(
+                slot_fn, out_axes=(0, 0, 1, 1))(tables, lengths, nxt)
+            return toks, nxts, rows_k, rows_v
+
+        def rows_in_place(params, k_pool, v_pool, tables, lengths, nxt):
+            with jax.named_scope("decode_pages"):
+                toks, nxts, rows_k, rows_v = model.decode_rows_paged(
+                    params, k_pool, v_pool, tables, lengths, nxt[:, 0], T)
+            return toks, nxts[:, None], rows_k, rows_v
+
+        rows_fn = rows_in_place if self.in_place else gathered_rows
+
+        def decode_paged(params, k_pool, v_pool, tables, lengths, nxt):
+            toks, nxts, rows_k, rows_v = rows_fn(
+                params, k_pool, v_pool, tables, lengths, nxt)
 
             def body(b, pools):
                 kp, vp = pools
                 tbl = tables[b]
                 start = jnp.minimum(lengths[b], S - T)
-                kp = scatter_block_rows(kp, tbl, rows_k[b], start)
-                vp = scatter_block_rows(vp, tbl, rows_v[b], start)
+                kp = scatter_block_rows(kp, tbl, rows_k[:, b], start)
+                vp = scatter_block_rows(vp, tbl, rows_v[:, b], start)
                 return kp, vp
 
             with jax.named_scope("kv_scatter"):
@@ -611,6 +644,16 @@ class PagedKV(KVCacheManager):
             self.engine.params, self.k_pool, self.v_pool, tables, lengths,
             self._nxt)
         self.counters.decode_launches += 1
+        # pages the attention reads: the kernel the live ones below each
+        # slot's window start, the gather every table row
+        active = self.lengths[self.ready]
+        table = len(active) * self.n_blk
+        read = table
+        if self.in_place:
+            start = np.minimum(active, self.engine.max_seq - self.engine.T)
+            read = int(np.sum(-(-start // self.pt)))
+        self.counters.decode_pages_table += table
+        self.counters.decode_pages_read += read
         with sess.span("serve.decode.readback"):
             blocks = np.asarray(toks)                # [B, T] host sync
         self.lengths[self.ready] += self.engine.T

@@ -12,7 +12,9 @@ depend on it.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +22,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.dma_copy.kernel import dma_copy_explicit, dma_copy_pipelined
+from repro.configs import ARCHS
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.paged_attention import ops as paged_ops
+from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.rms_norm.kernel import rms_norm_pallas
 from repro.kernels.ssd_scan.kernel import ssd_scan_pallas
 
@@ -89,3 +94,83 @@ def test_ssd_scan_mamba2_780m_widths(one_chip):
              sds((B, S, H, P), jnp.bfloat16), sds((B, S, H), jnp.float32),
              sds((H,), jnp.float32), sds((B, S, N), jnp.bfloat16),
              sds((B, S, N), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("hq,hk,hd,L,slots,n_blk", [
+    (32, 32, 128, 10, 7, 32), (32, 8, 128, 12, 6, 131), (8, 1, 256, 18, 8, 32),
+], ids=["deepseek-7b-l10", "qwen3-8b-l12", "gemma-2b"])
+def test_paged_attention_served_widths(one_chip, hq, hk, hd, L, slots, n_blk):
+    # the decode launch's kernel: whole page pools of 64-token pages
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((L, 1 + slots * n_blk, 64, hk, hd), jnp.bfloat16)
+    # also under the highest default precision, as chip_smoke.py checks it
+    for precision in ("default", "highest"):
+        with jax.default_matmul_precision(precision):
+            _compile(paged_attention_pallas,
+                     sds((slots, hq, hd), jnp.bfloat16), pool, pool,
+                     sds((slots, n_blk), jnp.int32),
+                     sds((slots,), jnp.int32), sds((), jnp.int32))
+
+
+_RESULT = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]")
+
+
+@pytest.mark.parametrize("arch,layers,slots,max_seq", [
+    ("deepseek-7b", 10, 7, 2048), ("qwen3-8b", 12, 6, 8384),
+], ids=["deepseek-7b-l10", "qwen3-8b-l12"])
+def test_paged_decode_launch_reads_pages_in_place(one_chip, monkeypatch, arch,
+                                                  layers, slots, max_seq):
+    """The benchmark's decode launch (``decode_paged``) at its cells'
+    widths, slots, positions and 64-token pages, on the kernel path: the
+    kernel is in the program, and nothing it computes is as large as one
+    slot's whole [L, S, Hkv, hd] cache besides the weights and the page
+    pools themselves (the undonated pools' copy and the scatter into it).
+    The gather of every slot's block table made such a cache per slot."""
+    from repro.runtime import kv as kv_mod
+    from repro.runtime.server import ContinuousBatchingServer
+
+    # the host is a CPU: steer the path and the kernel to the chip's
+    monkeypatch.setattr(kv_mod, "_attends_pages_in_place", lambda pool: True)
+    monkeypatch.setattr(paged_ops, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(ARCHS[arch], n_layers=layers,
+                              tie_embeddings=False)
+    box = {}
+
+    def build():
+        eng = ContinuousBatchingServer(
+            cfg, batch_size=slots, max_seq=max_seq, tokens_per_launch=4,
+            seed=0, kv="paged", kv_page_tokens=64, prefill_chunk=512)
+        box["kv"] = eng.kv
+        return eng.params, eng.kv.k_pool
+
+    params, pool = jax.eval_shape(build)
+    kv = box["kv"]
+    assert kv.counters.decode_body == "pages"
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    i32 = jnp.int32
+    compiled = _compile(kv._decode_slots.__wrapped__, *on_chip((
+        params, pool, pool, jax.ShapeDtypeStruct((slots, kv.n_blk), i32),
+        jax.ShapeDtypeStruct((slots,), i32),
+        jax.ShapeDtypeStruct((slots, 1, 1), i32))))
+    L, P, pt, hk, hd = pool.shape
+    slot_cache = L * max_seq * hk * hd
+    allowed = {pool.shape, (L, P, pt * hk, hd)} | {
+        x.shape for x in jax.tree_util.tree_leaves(params)}
+    large = []
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if m:
+            shape = tuple(int(d) for d in m.group(1).split(",") if d)
+            size = 1
+            for d in shape:
+                size *= d
+            if size >= slot_cache and shape not in allowed:
+                large.append(line.strip()[:160])
+    assert not large, large

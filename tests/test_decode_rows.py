@@ -9,7 +9,9 @@ launch's new rows (``TransformerLM.decode_rows``).
 * structure — no T-step loop of the paged launch's decode body carries
   anything as large as one slot's cache, so the per-token cache copy
   cannot return;
-* the ``decode_body``/``decode_launches`` counters of ``kv.stats()``.
+* the ``decode_body``/``decode_launches`` counters of ``kv.stats()``;
+* the paged launch that attends the pages in place through the paged
+  attention kernel (interpret mode here) against the dense backend.
 """
 import dataclasses
 
@@ -20,7 +22,9 @@ import pytest
 
 from repro.configs import SMOKE_ARCHS
 from repro.models import get_model
-from repro.runtime.server import ContinuousBatchingServer
+from repro.models.attention import gather_block_table
+from repro.runtime import kv as kv_mod
+from repro.runtime.server import ContinuousBatchingServer, Request
 from repro.runtime.traffic import TrafficSpec, generate, replay
 
 S, T = 32, 4
@@ -146,3 +150,59 @@ def test_kv_stats_report_decode_body(arch, kw, body):
     stats = _served_stats(arch, **kw)
     assert stats["decode_body"] == body
     assert stats["decode_launches"] > 0
+
+
+def _first_launch(cfg, prompts, **kw):
+    """An engine after one step: every prompt admitted and prefilled, then
+    one decode launch, with one slot left empty.  Slots are not released,
+    so a request that the launch finished keeps its state to compare."""
+    eng = ContinuousBatchingServer(cfg, batch_size=len(prompts) + 1,
+                                   max_seq=S, tokens_per_launch=T, seed=0,
+                                   **kw)
+    eng.kv.release = lambda slot: None
+    for i, p in enumerate(prompts):
+        eng.submit(Request(uid=i, prompt=p, max_new_tokens=2 * T))
+    eng.step()
+    assert eng.kv.counters.decode_launches == 1
+    return eng
+
+
+@pytest.mark.parametrize("arch", ["qwen3-8b", "gemma-2b"])
+def test_paged_kernel_launch_matches_dense(monkeypatch, arch):
+    """With the path function set to the kernel, the paged launch reads
+    the pages in place.  Its tokens equal the dense launch's, and so do
+    the next step's logits from the state each launch leaves (in float32,
+    to 1e-4): those depend on the K/V rows the launch wrote, each of which
+    past the first layer carries that launch's attention output."""
+    cfg = dataclasses.replace(SMOKE_ARCHS[arch], param_dtype="float32")
+    rs = np.random.default_rng(4)
+    # the last prompt ends past S - T: its window starts at the clamp
+    prompts = [list(rs.integers(1, cfg.vocab_size, size=n))
+               for n in (5, 13, S - T + 2)]
+    dense = _first_launch(cfg, prompts)
+    monkeypatch.setattr(kv_mod, "_attends_pages_in_place", lambda pool: True)
+    paged = _first_launch(cfg, prompts, kv="paged", kv_page_tokens=8)
+    kv = paged.kv
+    assert kv.stats()["decode_body"] == "pages"
+    for a, b in zip(dense.tickets, paged.tickets):
+        assert a.tokens == b.tokens
+    model, step = dense.model, jax.jit(dense.model.decode_step)
+    for slot in range(len(prompts)):
+        d = jax.tree_util.tree_map(lambda x: x[slot], dense.kv._slots)
+        table = jnp.asarray(kv.tables[slot])
+        p = {"k": gather_block_table(kv.k_pool, table),
+             "v": gather_block_table(kv.v_pool, table),
+             "length": jnp.asarray(kv.lengths[slot], jnp.int32)}
+        assert int(d["length"]) == int(p["length"])
+        np.testing.assert_allclose(
+            np.asarray(step(paged.params, p, kv._nxt[slot])[1]),
+            np.asarray(step(dense.params, d, dense.kv._nxt[slot])[1]),
+            rtol=1e-4, atol=1e-4)
+    stats = kv.stats()
+    starts = [min(len(p), S - T) for p in prompts]
+    assert stats["decode_pages_read"] == sum(-(-n // 8) for n in starts)
+    assert stats["decode_pages_table"] == len(prompts) * kv.n_blk
+    # the empty slot attended its window alone and wrote finite rows to
+    # the scratch page
+    assert np.isfinite(np.asarray(kv.k_pool[:, 0])).all()
+    assert np.isfinite(np.asarray(kv.v_pool[:, 0])).all()

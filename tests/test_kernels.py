@@ -117,3 +117,49 @@ def test_rms_norm_matches_model_layer():
     ref = model_rms({"scale": s}, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------- paged
+from repro.kernels.paged_attention.kernel import NEG_INF
+from repro.kernels.paged_attention.ops import paged_attention
+from repro.kernels.paged_attention.ref import paged_attention_ref
+
+PT = 8                                   # tokens a page
+# one slot for each read length: none, one position, a partial page, a
+# whole page, the whole table.  Page 0 is the scratch page every unused
+# table row points at; page 3 is shared by two slots, page 5 by three.
+PAGED_N = [0, 1, PT + 3, PT, 4 * PT]
+PAGED_TABLES = [[0, 0, 0, 0], [3, 0, 0, 0], [3, 5, 0, 0], [5, 0, 0, 0],
+                [2, 5, 6, 1]]
+
+
+@pytest.mark.parametrize("hq,hk,hd", [(32, 32, 128), (32, 8, 128),
+                                      (8, 1, 256)],
+                         ids=["mha", "gqa", "mqa"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_sweep(hq, hk, hd, dtype):
+    """Every read length, shared and scratch pages, layer 1 of 2, and a
+    panel of two pages, so the longer slots take several double-buffered
+    steps."""
+    L, P = 2, 7
+    k_pool = jnp.asarray(rng.normal(size=(L, P, PT, hk, hd)), dtype)
+    v_pool = jnp.asarray(rng.normal(size=(L, P, PT, hk, hd)), dtype)
+    q = jnp.asarray(rng.normal(size=(len(PAGED_N), hq, hd)), dtype)
+    tables = jnp.asarray(PAGED_TABLES, jnp.int32)
+    n = jnp.asarray(PAGED_N, jnp.int32)
+    acc, m, l = paged_attention(q, k_pool, v_pool, tables, n, jnp.int32(1),
+                                block_rows=2 * PT * hk)
+    r_acc, r_m, r_l = paged_attention_ref(q, k_pool, v_pool, tables, n, 1)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 3e-5
+    live = np.asarray(PAGED_N) > 0
+    out = np.asarray(acc / l[..., None])[live]
+    ref = np.asarray(r_acc / r_l[..., None])[live]
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(m), np.asarray(r_m),
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(l), np.asarray(r_l),
+                               rtol=tol, atol=tol)
+    # a slot with nothing to read merges as empty: no weight, no NaN
+    assert not np.asarray(acc)[~live].any()
+    assert not np.asarray(l)[~live].any()
+    assert (np.asarray(m)[~live] == NEG_INF).all()
