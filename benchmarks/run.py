@@ -5,7 +5,6 @@
   bench_submission — §6.2/§7 (stage decomposition, multi-step economy)
   bench_policy     — tuned-policy before/after (python -m repro.tune)
   bench_loadtest   — continuous-batching serve under Poisson traffic
-  bench_kv         — dense vs paged KV backends on shared-prefix traffic
   bench_kernels    — per-kernel interpret-mode sanity timings
 
 Prints ``name,value...`` CSV blocks (unchanged), and additionally writes a
@@ -103,8 +102,8 @@ def main() -> None:
     from repro.launch.mesh import enable_compile_cache
     from repro.tune.policy import load_policy
 
-    from . import (bench_dma, bench_graphs, bench_kv, bench_loadtest,
-                   bench_policy, bench_submission)
+    from . import (bench_dma, bench_graphs, bench_loadtest, bench_policy,
+                   bench_submission)
 
     enable_compile_cache()
 
@@ -136,10 +135,6 @@ def main() -> None:
                  bench_loadtest.HEADER,
                  bench_loadtest.run(arch=args.arch, quick=args.quick,
                                     session=sess))
-        _section("kv", "KV backends: dense vs paged (shared-prefix)",
-                 bench_kv.HEADER,
-                 bench_kv.run(arch=args.arch, quick=args.quick,
-                              session=sess))
         _section("kernels", "Kernel interpret-mode timings", "name,ms",
                  bench_kernels_rows())
     summary = sess.summary()

@@ -9,8 +9,8 @@ process, three phases; any failure raises and exits nonzero:
 
 1. serve   -- ``repro.launch.loadtest`` drives the continuous-batching
    engine with the published ``gemma-2b`` config (random weights from a
-   seed), once with the dense KV backend and once paged with chunked
-   prefill and a shared prompt prefix.  Every engine knob is passed on the
+   seed) on its paged KV cache, with chunked prefill and a shared prompt
+   prefix.  Every engine knob is passed on the
    command line, so no tuned policy file is read.  ``--verify`` replays
    requests through one-shot ``Server.serve`` and reports whether the
    tokens are bit-identical.
@@ -109,31 +109,27 @@ def report_compiles(log: CompileLog, label: str) -> None:
 
 
 # ----------------------------------------------------------------- phase 1
-def loadtest_argv(kv: str, json_path: str) -> List[str]:
-    argv = ["--arch", "gemma-2b", "--full", "--batch", str(SLOTS),
+def loadtest_argv(json_path: str) -> List[str]:
+    return ["--arch", "gemma-2b", "--full", "--batch", str(SLOTS),
             "--max-seq", str(MAX_SEQ), "--tokens-per-launch", str(T),
             "--max-pending", "256", "--admission", "reject",
             "--sched", "fifo", "--requests", str(REQUESTS),
             "--rate", "100", "--prompt-lens", PROMPT_LENS,
             "--new-tokens", str(NEW_TOKENS), "--seed", str(SEED),
-            "--verify", str(VERIFY), "--json", json_path, "--kv", kv]
-    if kv == "paged":
-        argv += ["--kv-page-tokens", str(PAGE_TOKENS),
-                 "--prefill-chunk", str(PREFILL_CHUNK),
-                 "--prefix-len", str(PREFIX_LEN)]
-    else:
-        argv += ["--prefill-chunk", "0", "--prefix-len", "0"]
-    return argv
+            "--verify", str(VERIFY), "--json", json_path,
+            "--kv-page-tokens", str(PAGE_TOKENS),
+            "--prefill-chunk", str(PREFILL_CHUNK),
+            "--prefix-len", str(PREFIX_LEN)]
 
 
-def phase_serve(kv: str) -> bool:
+def phase_serve() -> bool:
     """One loadtest run; returns whether its tokens matched one-shot serve."""
     from repro.launch import loadtest
 
     os.makedirs(OUT_DIR, exist_ok=True)
-    path = os.path.join(OUT_DIR, f"loadtest_{kv}.json")
-    argv = loadtest_argv(kv, path)
-    print(f"[serve/{kv}] python -m repro.launch.loadtest {' '.join(argv)}")
+    path = os.path.join(OUT_DIR, "loadtest_paged.json")
+    argv = loadtest_argv(path)
+    print(f"[serve] python -m repro.launch.loadtest {' '.join(argv)}")
     t0 = time.perf_counter()
     rc = loadtest.main(argv)
     wall = time.perf_counter() - t0
@@ -143,24 +139,22 @@ def phase_serve(kv: str) -> bool:
     # loadtest exits 1 only when --verify finds a token mismatch; that is a
     # reported finding here, anything else is a failure
     check(rc == 0 or (rc == 1 and not verified["ok"]),
-          f"loadtest --kv {kv} returned {rc}")
+          f"loadtest returned {rc}")
     check(m["requests"] == REQUESTS and m["completed"] == REQUESTS,
-          f"{kv}: {m['completed']}/{REQUESTS} requests completed "
+          f"{m['completed']}/{REQUESTS} requests completed "
           f"(evicted={m['evicted']} rejected={m['rejected']})")
     check(m["new_tokens"] == REQUESTS * NEW_TOKENS,
-          f"{kv}: {m['new_tokens']} tokens, expected "
-          f"{REQUESTS * NEW_TOKENS}")
+          f"{m['new_tokens']} tokens, expected {REQUESTS * NEW_TOKENS}")
     check(all(t["status"] == "done" and t["n_tokens"] == NEW_TOKENS
-              for t in rec["tickets"]), f"{kv}: a ticket did not finish")
+              for t in rec["tickets"]), "a ticket did not finish")
     kvs = rec["kv"]
-    if kv == "paged":
-        check(kvs["prefix_hits"] > 0, "paged: no shared-prefix page reuse")
-        check(kvs["chunked_prompts"] > 0, "paged: no prompt was chunked")
-    print(f"[serve/{kv}] smoke reading, not a benchmark: wall {wall:.1f} s "
+    check(kvs["prefix_hits"] > 0, "no shared-prefix page reuse")
+    check(kvs["chunked_prompts"] > 0, "no prompt was chunked")
+    print(f"[serve] smoke reading, not a benchmark: wall {wall:.1f} s "
           f"including compiles; {m['new_tokens']} tokens in "
           f"{m['doorbells']} doorbells; prefill launches "
           f"{kvs['prefill_launches']}")
-    print(f"[serve/{kv}] bit-identical to one-shot Server.serve: "
+    print(f"[serve] bit-identical to one-shot Server.serve: "
           f"{verified['ok']} ({VERIFY} requests)")
     return verified["ok"]
 
@@ -325,12 +319,10 @@ def main() -> int:
     jax.monitoring.register_event_duration_secs_listener(log.on_duration)
     jax.monitoring.register_event_listener(log.on_event)
 
-    identical = {}
-    for kv in ("dense", "paged"):
-        identical[kv] = phase_serve(kv)
-        report_compiles(log, f"serve/{kv}")
-        gc.collect()                    # engines and weights die with loadtest
-        report_memory(dev, f"serve/{kv}")
+    identical = phase_serve()
+    report_compiles(log, "serve")
+    gc.collect()                        # engines and weights die with loadtest
+    report_memory(dev, "serve")
 
     err = phase_logits(ARCHS["gemma-2b"])
     report_compiles(log, "logits")
@@ -341,9 +333,8 @@ def main() -> int:
     report_compiles(log, "kernels")
     report_memory(dev, "kernels")
 
-    print(f"phases passed: serve/dense serve/paged logits "
-          f"(max err {err:.3e}) kernels; bit-identical to one-shot serve: "
-          + ", ".join(f"{kv}={ok}" for kv, ok in identical.items()))
+    print(f"phases passed: serve logits (max err {err:.3e}) kernels; "
+          f"bit-identical to one-shot serve: {identical}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

@@ -68,14 +68,11 @@ def main(argv=None) -> int:
     ap.add_argument("--max-pending", type=int, default=256)
     ap.add_argument("--admission", default="reject",
                     choices=("reject", "drop_oldest"))
-    ap.add_argument("--kv", default="dense", choices=("dense", "paged"),
-                    help="KV-cache backend (paged adds block tables + "
-                         "shared-prefix page reuse)")
     ap.add_argument("--kv-page-tokens", type=int, default=None,
-                    help="paged backend page size in tokens "
+                    help="KV page size in tokens "
                          "(unset -> tuned policy, fallback 16)")
     ap.add_argument("--kv-pages", type=int, default=None,
-                    help="paged pool size in pages (unset -> every slot "
+                    help="KV pool size in pages (unset -> every slot "
                          "fully grown: exhaustion impossible)")
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="max tokens per prefill launch; longer prompts "
@@ -164,8 +161,8 @@ def main(argv=None) -> int:
             cfg, batch_size=args.batch, max_seq=args.max_seq,
             tokens_per_launch=args.tokens_per_launch, seed=args.seed,
             session=sess, max_pending=args.max_pending,
-            admission=args.admission, kv=args.kv,
-            kv_page_tokens=args.kv_page_tokens, kv_pages=args.kv_pages,
+            admission=args.admission, kv_page_tokens=args.kv_page_tokens,
+            kv_pages=args.kv_pages,
             prefill_chunk=args.prefill_chunk, sched=args.sched)
         live_srv = None
         if args.live is not None:
@@ -176,7 +173,8 @@ def main(argv=None) -> int:
         print(f"loadtest: arch={cfg.name} slots={args.batch} T={eng.T} "
               f"requests={spec.n_requests} rate={spec.rate}/s "
               f"realtime={args.realtime} admission={args.admission} "
-              f"kv={eng.kv.name} chunk={eng.kv.chunk} sched={args.sched}")
+              f"page_tokens={eng.kv.pt} chunk={eng.kv.chunk} "
+              f"sched={args.sched}")
         try:
             tickets, metrics = replay(eng, arrivals, realtime=args.realtime,
                                       speed=args.speed)
@@ -198,13 +196,11 @@ def main(argv=None) -> int:
           f"({metrics['new_tokens']} tokens / {metrics['doorbells']} "
           f"doorbells)")
     kv = metrics["kv"]
-    print(f"kv[{kv['backend']}] prefill launches={kv['prefill_launches']} "
+    print(f"kv prefill launches={kv['prefill_launches']} "
           f"payload={kv['prefill_payload_bytes']}B "
-          f"chunked={kv['chunked_prompts']}"
-          + (f"  pages peak={kv['pages_peak']}/{kv['pages_total']} "
-             f"reused={kv['pages_reused']} "
-             f"prefix_hits={kv['prefix_hits']}"
-             if kv["backend"] == "paged" else ""))
+          f"chunked={kv['chunked_prompts']}  "
+          f"pages peak={kv['pages_peak']}/{kv['pages_total']} "
+          f"reused={kv['pages_reused']} prefix_hits={kv['prefix_hits']}")
     req_attr = prof.path("serve.request")
     if req_attr:
         db, wall = req_attr["doorbells_per_span"], req_attr["wall_s"]
@@ -244,9 +240,8 @@ def main(argv=None) -> int:
                        "admission": args.admission,
                        "realtime": args.realtime,
                        "sched": args.sched},
-            # KV backend footprint: prefill launches/payload, page pool
-            # occupancy, prefix-hit reuse — the dense-vs-paged comparison
-            # the README table and BENCH kv section are built from
+            # KV footprint: prefill launches/payload, page pool occupancy,
+            # prefix-hit reuse
             "kv": metrics["kv"],
             "traffic": spec.to_dict(),
             "metrics": metrics,

@@ -41,8 +41,6 @@ def main() -> None:
                          "(python -m repro.tune), else 4")
     ap.add_argument("--continuous", action="store_true",
                     help="serve through the continuous-batching engine")
-    ap.add_argument("--kv", default="dense", choices=("dense", "paged"),
-                    help="with --continuous: KV-cache backend")
     ap.add_argument("--kv-page-tokens", type=int, default=None,
                     help="paged page size in tokens (unset -> tuned/16)")
     ap.add_argument("--prefill-chunk", type=int, default=None,
@@ -80,7 +78,7 @@ def main() -> None:
         srv = ContinuousBatchingServer(
             cfg, batch_size=args.batch, max_seq=args.max_seq,
             tokens_per_launch=tpl, seed=args.seed, session=session,
-            kv=args.kv, kv_page_tokens=args.kv_page_tokens,
+            kv_page_tokens=args.kv_page_tokens,
             prefill_chunk=args.prefill_chunk, sched=args.sched)
     else:
         srv = Server(cfg, batch_size=args.batch, max_seq=args.max_seq,

@@ -16,7 +16,7 @@ from ..configs.base import ModelConfig
 from .layers import Params, apply_rope, init_rms_norm, rms_norm, rotary
 
 __all__ = ["init_attention", "attention", "decode_attention",
-           "decode_attention_rows", "decode_attention_paged", "init_kv_cache",
+           "decode_attention_paged", "init_kv_cache",
            "chunked_causal_attention", "dense_causal_attention",
            "extend_attention", "gather_block_table", "scatter_block_rows"]
 
@@ -264,25 +264,6 @@ def _decode_qkv(p: Params, cfg: ModelConfig, x: jax.Array, pos: jax.Array):
     return q, k_new, v_new
 
 
-def _decode_attend(p: Params, cfg: ModelConfig, q: jax.Array,
-                   k_cache: jax.Array, v_cache: jax.Array,
-                   valid: jax.Array) -> jax.Array:
-    """One query row against a whole [B, S_max, Hkv, hd] cache, positions
-    where ``valid`` [S_max] is false masked to exactly zero weight."""
-    B = q.shape[0]
-    h, hk, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.hd
-    n_rep = h // hk
-    scale = hd ** -0.5
-    q_ = q.reshape(B, hk, n_rep, hd)
-    scores = jnp.einsum("bgrd,bsgd->bgrs", q_, k_cache).astype(jnp.float32) * scale
-    scores = jnp.where(valid[None, None, None, :], scores,
-                       jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bgrs,bsgd->bgrd", probs.astype(v_cache.dtype), v_cache)
-    out = out.reshape(B, 1, h, hd)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-
-
 def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
                      k_cache: jax.Array, v_cache: jax.Array,
                      length: jax.Array,
@@ -291,51 +272,26 @@ def decode_attention(p: Params, cfg: ModelConfig, x: jax.Array,
 
     x: [B, 1, D]; k_cache/v_cache: [B, S_max, Hkv, hd]; length: [] int32 —
     number of valid cache positions (the new token is written at ``length``).
-    Returns (out [B,1,D], new_k_cache, new_v_cache).
+    The query scores the whole cache; positions past ``length`` are masked
+    to exactly zero weight.  Returns (out [B,1,D], new_k_cache, new_v_cache).
     """
-    S = k_cache.shape[1]
+    B, S = k_cache.shape[:2]
+    h, hk, hd = cfg.n_heads_padded, cfg.n_kv_heads, cfg.hd
     q, k_new, v_new = _decode_qkv(p, cfg, x, length)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new.astype(k_cache.dtype), (0, length, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(
         v_cache, v_new.astype(v_cache.dtype), (0, length, 0, 0))
-    # scores against the whole cache; invalid positions masked by length
-    out = _decode_attend(p, cfg, q, k_cache, v_cache, jnp.arange(S) <= length)
-    return out, k_cache, v_cache
-
-
-def decode_attention_rows(p: Params, cfg: ModelConfig, x: jax.Array,
-                          k_cache: jax.Array, v_cache: jax.Array,
-                          rows_k: jax.Array, rows_v: jax.Array,
-                          start: jax.Array, pos: jax.Array,
-                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token attention against a read-only cache plus a row window.
-
-    x: [B, 1, D] at position ``pos``; k_cache/v_cache: [B, S_max, Hkv, hd],
-    read only; rows_k/rows_v: [B, T, Hkv, hd], the window standing for
-    cache positions start..start+T-1.  The new K/V row goes into the window
-    where :func:`decode_attention` would write the cache (``pos`` clamped
-    to S_max - 1).  The query then attends the cache with the window
-    selected in at its positions: the same S_max positions holding the
-    same rows as :func:`decode_attention`'s written cache, through the
-    same attention body, so both give the same bits, while the selection
-    feeds the attention and nothing of cache size is written.
-    Returns (out [B,1,D], rows_k, rows_v).
-    """
-    S, T = k_cache.shape[1], rows_k.shape[1]
-    q, k_new, v_new = _decode_qkv(p, cfg, x, pos)
-    w = jnp.minimum(pos, S - 1) - start
-    rows_k = jax.lax.dynamic_update_slice(
-        rows_k, k_new.astype(rows_k.dtype), (0, w, 0, 0))
-    rows_v = jax.lax.dynamic_update_slice(
-        rows_v, v_new.astype(rows_v.dtype), (0, w, 0, 0))
-    s = jnp.arange(S)
-    k, v = k_cache, v_cache
-    for j in range(T):          # one broadcast row each: no gather to write
-        here = (s == start + j)[None, :, None, None]
-        k = jnp.where(here, rows_k[:, j:j + 1], k)
-        v = jnp.where(here, rows_v[:, j:j + 1], v)
-    return _decode_attend(p, cfg, q, k, v, s <= pos), rows_k, rows_v
+    q_ = q.reshape(B, hk, h // hk, hd)
+    scores = jnp.einsum("bgrd,bsgd->bgrs", q_, k_cache).astype(
+        jnp.float32) * hd ** -0.5
+    valid = jnp.arange(S) <= length
+    scores = jnp.where(valid[None, None, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bgrs,bsgd->bgrd", probs.astype(v_cache.dtype), v_cache)
+    out = out.reshape(B, 1, h, hd)
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), k_cache, v_cache
 
 
 def decode_attention_paged(p: Params, cfg: ModelConfig, x: jax.Array,
@@ -346,12 +302,12 @@ def decode_attention_paged(p: Params, cfg: ModelConfig, x: jax.Array,
                            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-token attention of every slot, reading the pages in place.
 
-    The paged form of :func:`decode_attention_rows` over all B slots at
-    once.  x: [B, 1, D], slot b at position ``pos[b]``; k_pool/v_pool:
-    [L, P, page_tokens, Hkv, hd], read only, of which ``layer`` is this
-    layer; tables: [B, n_blk]; rows_k/rows_v: [B, T, Hkv, hd], the window
-    standing for positions start..start+T-1 of each slot.  The new K/V row
-    goes into the window as there.  The query then attends two parts,
+    x: [B, 1, D], slot b at position ``pos[b]``; k_pool/v_pool: [L, P,
+    page_tokens, Hkv, hd], read only, of which ``layer`` is this layer;
+    tables: [B, n_blk]; rows_k/rows_v: [B, T, Hkv, hd], the window standing
+    for positions start..start+T-1 of each slot.  The new K/V row goes into
+    the window where :func:`decode_attention` would write the cache
+    (``pos`` clamped to S - 1).  The query then attends two parts,
     merged by log-sum-exp: positions [0, start) through the paged
     attention kernel, which copies only those pages, and the window rows
     up to ``min(pos, S - 1)``.  Scores and softmax are float32; a slot
@@ -439,9 +395,8 @@ def gather_block_table(pool: jax.Array, table: jax.Array) -> jax.Array:
     """Gather one slot's pages into a contiguous batch-1 cache.
 
     pool: [L, P, page_tokens, Hkv, hd]; table: [n_blk] int32 physical page
-    ids.  Returns [L, 1, n_blk*page_tokens, Hkv, hd] — the same layout the
-    dense decode path uses, so the decode math downstream is shared (and
-    bit-identical) between backends.
+    ids.  Returns [L, 1, n_blk*page_tokens, Hkv, hd], the decode-state
+    layout ``prefill_extend`` takes.
     """
     L, P, pt, Hk, hd = pool.shape
     g = pool[:, table]  # [L, n_blk, pt, Hk, hd]
@@ -452,9 +407,9 @@ def scatter_block_rows(pool: jax.Array, table: jax.Array, rows: jax.Array,
                        start: jax.Array) -> jax.Array:
     """Write ``rows`` [L, n, Hkv, hd] at logical positions start..start+n-1.
 
-    Positions are clamped exactly the way ``dynamic_update_slice`` clamps the
-    dense cache write (overshoot past max_seq lands in the final page), so a
-    request finishing at the KV cap behaves identically to dense.
+    Positions are clamped exactly the way ``dynamic_update_slice`` clamps a
+    contiguous cache write (overshoot past max_seq lands in the final page),
+    so a request finishing at the KV cap writes where ``decode_step`` would.
     """
     L, P, pt, Hk, hd = pool.shape
     n_blk = table.shape[0]

@@ -13,8 +13,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from .attention import (attention, decode_attention, decode_attention_paged,
-                        decode_attention_rows, extend_attention,
-                        init_attention, init_kv_cache)
+                        extend_attention, init_attention, init_kv_cache)
 from .layers import (Params, cross_entropy_loss, dtype_of, embed,
                      init_embedding, init_mlp, init_rms_norm, mlp, rms_norm,
                      unembed)
@@ -68,17 +67,6 @@ def block_decode(p: Params, cfg: ModelConfig, x: jax.Array,
     a, k_cache, v_cache = decode_attention(
         p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache, length)
     return _serve_ffn(p, cfg, x + a), k_cache, v_cache
-
-
-def block_decode_rows(p: Params, cfg: ModelConfig, x: jax.Array,
-                      k_cache: jax.Array, v_cache: jax.Array,
-                      rows_k: jax.Array, rows_v: jax.Array,
-                      start: jax.Array, pos: jax.Array
-                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    a, rows_k, rows_v = decode_attention_rows(
-        p["attn"], cfg, rms_norm(p["ln1"], x), k_cache, v_cache,
-        rows_k, rows_v, start, pos)
-    return _serve_ffn(p, cfg, x + a), rows_k, rows_v
 
 
 def block_decode_paged(p: Params, cfg: ModelConfig, x: jax.Array,
@@ -240,64 +228,23 @@ class TransformerLM:
         new_state = {"k": new_k, "v": new_v, "length": length + 1}
         return new_state, logits
 
-    def decode_rows(self, params: Params, k_cache: jax.Array,
-                    v_cache: jax.Array, length: jax.Array, tok: jax.Array,
-                    T: int):
-        """T greedy tokens of one sequence against a read-only cache.
-
-        k_cache/v_cache: [L, 1, S, Hkv, hd] with ``length`` valid positions;
-        tok: [1, 1], the token at position ``length``.  The T token steps
-        carry only the token and a T-row window per layer, standing for
-        cache positions start..start+T-1 with start = min(length, S - T):
-        it ends holding exactly what T ``decode_step`` calls would leave
-        there, clamped final-position writes included, so writing it back
-        at ``start`` updates the cache as they would.  Each step attends
-        the cache with the window selected in, through the body
-        ``decode_step`` uses, so the tokens are the same bits.  Returns
-        (toks [T], nxt [1, 1], rows_k, rows_v [L, T, Hkv, hd]).
-        """
-        cfg = self.cfg
-        L, _, S, Hk, hd = k_cache.shape
-        start = jnp.minimum(length, S - T)
-
-        def window(c):
-            return jax.lax.dynamic_slice(c, (0, 0, start, 0, 0),
-                                         (L, 1, T, Hk, hd))
-
-        def step(carry, t):
-            tok, rk, rv = carry
-            x = embed(params["emb"], tok, cfg.embed_scale)
-
-            def layer(x, inp):
-                lp, kc, vc, rk_l, rv_l = inp
-                x, rk_l, rv_l = block_decode_rows(
-                    lp, cfg, x, kc, vc, rk_l, rv_l, start, length + t)
-                return x, (rk_l, rv_l)
-
-            x, (rk, rv) = jax.lax.scan(
-                layer, x, (params["layers"], k_cache, v_cache, rk, rv))
-            logits = unembed(params["emb"], rms_norm(params["final_norm"], x))
-            nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
-            return (nxt, rk, rv), nxt[0, 0]
-
-        (nxt, rk, rv), toks = jax.lax.scan(
-            step, (tok, window(k_cache), window(v_cache)), jnp.arange(T))
-        return toks, nxt, rk[:, 0], rv[:, 0]
-
     def decode_rows_paged(self, params: Params, k_pool: jax.Array,
                           v_pool: jax.Array, tables: jax.Array,
                           lengths: jax.Array, tok: jax.Array, T: int):
         """T greedy tokens of every slot, attending the pages in place.
 
-        The paged form of :meth:`decode_rows` over all B slots at once.
         k_pool/v_pool: [L, P, page_tokens, Hkv, hd], read only; tables:
         [B, n_blk] page ids; lengths: [B] valid positions; tok: [B, 1], the
-        token at position ``lengths``.  Each slot's window starts from the
-        pool's rows at start = min(length, S - T) (at most two pages) and
-        ends holding what :meth:`decode_rows` leaves in it.  The layer scan
-        passes the layer index and never scans over the pools, which the
-        paged attention kernel reads where they lie.  Returns (toks [B, T],
-        nxt [B, 1], rows_k, rows_v [L, B, T, Hkv, hd]).
+        token at position ``lengths``.  The T token steps carry only the
+        token and a T-row window per layer, standing for cache positions
+        start..start+T-1 with start = min(length, S - T) (at most two
+        pages): it starts from the pool's rows there and ends holding what
+        T ``decode_step`` calls would leave at those positions, clamped
+        final-position writes included, so scattering it back at ``start``
+        updates the cache as they would.  The layer scan passes the layer
+        index and never scans over the pools, which the paged attention
+        kernel reads where they lie.  Returns (toks [B, T], nxt [B, 1],
+        rows_k, rows_v [L, B, T, Hkv, hd]).
         """
         cfg = self.cfg
         L, _, pt = k_pool.shape[:3]

@@ -1,42 +1,39 @@
 """KV-cache management for the continuous-batching engine.
 
-The serve engine's scale bottleneck is its memory path: PR 7 gave every
-slot a full ``max_seq`` dense cache and prefilled whole prompts in one
-launch, stalling the decode loop.  This module extracts that state handling
-behind :class:`KVCacheManager` with two backends:
+:class:`PagedKV` is the engine's KV manager: one global page pool ``[L,
+1 + pages, page_tokens, Hkv, hd]`` for K and one for V, with a block table
+per slot.  Its two programs are the serving path on every platform:
 
-* :class:`DenseKV` — the original layout: one stacked batch-1
-  decode-state pytree per slot, whole-prompt prefill, one vmapped
-  ``decode_slots`` launch, token-bit-identical to the pre-refactor
-  engine (a transformer's rows body attends what ``decode_step`` would,
-  through the same attention body).
-* :class:`PagedKV` — a single global page pool ``[L, pages, page_tokens,
-  Hkv, hd]`` with per-slot block tables.  On a TPU a decode launch attends
-  each slot's pages in place, up to its length, through the paged
-  attention kernel (``decode_body`` ``"pages"``).  Elsewhere it gathers
-  each slot's pages into the *same contiguous layout the dense path
-  decodes*, runs the identical decode math (``"rows"``) — so paged tokens
-  are bit-identical to dense there, the oracle of the kernel path.  Either
-  way the new rows are scattered back.  Pages holding a fully-prefilled
-  prompt prefix are content-addressed (hash chain over page tokens) and
-  shared across requests with the same prefix: a prefix hit skips those
-  prefill tokens entirely, which is what makes the per-request command
-  footprint (prefill doorbells, DMA payload bytes) sublinear in
-  shared-prefix traffic.  Pool exhaustion evicts with ``reason="kv_pages"``
-  (the dense ``kv_overrun`` cap semantics are preserved by the engine in
-  both backends).
+* the extend launch (``extend_paged``) gathers the slot's block table,
+  runs ``prefill_extend`` over one prompt chunk (or the whole prompt) and
+  scatters the chunk's K/V rows back into the slot's pages;
+* the decode launch (``decode_paged``) runs T greedy tokens of every slot
+  through ``decode_rows_paged``, which attends each slot's pages in place,
+  up to its length, through the paged attention kernel
+  (``kernels/paged_attention``; interpreted on the CPU, compiled on a
+  TPU), then scatters the T new rows back.
 
-Both backends support **chunked prefill**: prompts longer than
-``prefill_chunk`` are advanced one bounded ``prefill_extend`` launch at a
-time (``serve.prefill_chunk`` spans), interleaved by the engine with decode
-iterations so long prompts no longer stall active slots.  Chunked prefill
-is bit-identical to whole-prompt prefill (masked-out future cache positions
-contribute exactly-zero softmax weight; see ``models.attention``).
+Pages holding a fully-prefilled prompt prefix are content-addressed (hash
+chain over page tokens) and shared across requests with the same prefix: a
+prefix hit skips those prefill tokens entirely, which is what makes the
+per-request command footprint (prefill doorbells, DMA payload bytes)
+sublinear in shared-prefix traffic.  Pool exhaustion evicts with
+``reason="kv_pages"``; the engine caps each request at ``max_seq``
+(``kv_overrun``).
+
+**Chunked prefill**: prompts longer than ``prefill_chunk`` advance one
+bounded extend launch at a time (``serve.prefill_chunk`` spans),
+interleaved by the engine with decode iterations so long prompts do not
+stall active slots.  Chunked prefill is bit-identical to whole-prompt
+prefill (masked-out future cache positions contribute exactly-zero softmax
+weight; see ``models.attention``).
 
 Page 0 of the pool is a reserved scratch page: free or still-prefilling
-slots point every block-table row at it with length 0, so the vmapped
-decode launch stays total and shape-stable — exactly the property the dense
-backend gets from keeping a well-formed state in every slot.
+slots point every block-table row at it with length 0, so the decode
+launch stays total and shape-stable across joins and leaves.
+
+The engine's tokens are checked against one-shot ``Server.serve`` of the
+same request, which runs the model's own ``prefill`` and ``decode_step``.
 """
 from __future__ import annotations
 
@@ -55,259 +52,15 @@ if TYPE_CHECKING:                                    # pragma: no cover
     from .scheduler import RequestTicket
     from .server import Server
 
-__all__ = ["KVCacheManager", "DenseKV", "PagedKV", "make_kv", "KV_BACKENDS"]
-
-KV_BACKENDS = ("dense", "paged")
+__all__ = ["PagedKV"]
 
 
-def _decode_body(model) -> str:
-    """Which per-slot decode body a model gets: ``"rows"`` where it offers
-    ``decode_rows`` (the transformer family: the T token steps read the
-    cache and carry only the launch's new rows), else ``"carry"`` (the T
-    steps carry the whole decode state)."""
-    return "rows" if hasattr(model, "decode_rows") else "carry"
-
-
-def _attends_pages_in_place(pool: jax.Array) -> bool:
-    """Whether a paged decode launch over ``pool`` reads the pages in place
-    through the paged attention kernel: on a TPU, where the kernel
-    compiles.  Elsewhere the launch gathers the block tables and runs
-    ``decode_rows``, the bit-identical twin of the dense path."""
-    if isinstance(pool, jax.core.Tracer):     # built abstractly
-        return jax.default_backend() == "tpu"
-    return all(d.platform == "tpu" for d in pool.devices())
-
-
-def _decode_slot_fn(model, T: int):
-    """The dense per-slot decode body: T greedy steps, one launch.
-
-    With ``decode_rows`` it is the body the paged launch runs too, the
-    rows written back with one ``dynamic_update_slice``, whose start
-    clamps to ``S - T`` exactly as the paged scatter's does — which is
-    what makes the two backends' token streams bit-identical.
-    """
-    if _decode_body(model) == "rows":
-        def decode_rows_slot(params, state, tok):
-            length = state["length"]
-            toks, nxt, rk, rv = model.decode_rows(
-                params, state["k"], state["v"], length, tok, T)
-            at = (0, 0, length, 0, 0)
-            k = jax.lax.dynamic_update_slice(state["k"], rk[:, None], at)
-            v = jax.lax.dynamic_update_slice(state["v"], rv[:, None], at)
-            return {"k": k, "v": v, "length": length + T}, toks, nxt
-
-        return decode_rows_slot
-
-    def decode_slot(params, state, tok):             # state: batch-1 pytree
-        def body(carry, _):
-            st, t = carry
-            st, logits = model.decode_step(params, st, t)
-            nxt = jnp.argmax(logits[:, -1:, :], axis=-1).astype(t.dtype)
-            return (st, nxt), nxt[0, 0]
-        (state, nxt), toks = jax.lax.scan(
-            body, (state, tok), None, length=T)
-        return state, toks, nxt                      # [T], [1, 1]
-
-    return decode_slot
-
-
-class KVCacheManager:
-    """Backend interface the engine schedules against.
-
-    Lifecycle per request: :meth:`begin` claims resources for a slot
-    (returns False only on unrecoverable page exhaustion -> the engine
-    evicts with ``reason="kv_pages"``); :meth:`prefill_step` advances the
-    slot's prefill by at most one launch and returns the first generated
-    token once the prompt is fully in cache; :meth:`reserve_decode` grows
-    per-slot capacity ahead of a decode launch (returning victims when the
-    pool cannot); :meth:`decode` runs the one vmapped launch over all
-    slots; :meth:`release` returns the slot's memory.
-    """
-
-    name = "none"
-    chunk = 0                    # prefill_chunk knob (0 = whole-prompt)
-
-    def begin(self, slot: int, tix: "RequestTicket") -> bool:
-        raise NotImplementedError
-
-    def prefill_step(self, slot: int) -> Optional[int]:
-        raise NotImplementedError
-
-    def reserve_decode(self, slots: List[int]) -> List[int]:
-        return []
-
-    def decode(self) -> np.ndarray:
-        raise NotImplementedError
-
-    def release(self, slot: int) -> None:
-        pass
-
-    def stats(self) -> Dict[str, Any]:
-        raise NotImplementedError
-
-
-class _LaunchCounters:
-    """Shared launch/byte accounting (feeds loadtest --json and BENCH)."""
-
-    def __init__(self, decode_body: str) -> None:
-        self.decode_body = decode_body   # body chosen at construction
-        self.decode_launches = 0
-        self.prefill_launches = 0        # all prefill/extend launches
-        self.prefill_chunk_launches = 0  # the subset that were chunk ticks
-        self.prefill_tokens = 0          # prompt tokens actually pushed
-        self.chunked_prompts = 0         # prompts that needed >1 launch
-        # query-key pairs of the extend launches: every one the attention
-        # scores (each query against the whole cache buffer) and those the
-        # causal mask keeps (each query against itself and what precedes it)
-        self.extend_scored_positions = 0
-        self.extend_live_positions = 0
-        # pages of the paged decode launches: those the attention reads
-        # and those the active slots' block tables hold
-        self.decode_pages_read = 0
-        self.decode_pages_table = 0
-
-    def count_extend(self, start: int, size: int, buffer: int
-                     ) -> Dict[str, int]:
-        """Count one extend launch of ``size`` prompt positions from
-        ``start`` against a cache of ``buffer`` positions; returns the
-        launch's ``scored`` and ``live`` pairs, for its span."""
-        scored = size * buffer
-        live = size * start + size * (size + 1) // 2
-        self.extend_scored_positions += scored
-        self.extend_live_positions += live
-        return {"scored": scored, "live": live}
-
-    @property
-    def prefill_payload_bytes(self) -> int:
-        return 4 * self.prefill_tokens
-
-    def to_dict(self) -> Dict[str, Any]:
-        return {
-            "decode_body": self.decode_body,
-            "decode_launches": self.decode_launches,
-            "prefill_launches": self.prefill_launches,
-            "prefill_chunk_launches": self.prefill_chunk_launches,
-            "prefill_tokens": self.prefill_tokens,
-            "prefill_payload_bytes": self.prefill_payload_bytes,
-            "chunked_prompts": self.chunked_prompts,
-            "extend_scored_positions": self.extend_scored_positions,
-            "extend_live_positions": self.extend_live_positions,
-            "decode_pages_read": self.decode_pages_read,
-            "decode_pages_table": self.decode_pages_table,
-        }
-
-
-def _require_extend(model, why: str) -> None:
+def _require_extend(model) -> None:
     if not hasattr(model, "prefill_extend"):
         raise ValueError(
-            f"{why} requires a model with a prefill_extend() decode-state "
-            f"extension (transformer-family); {type(model).__name__} has "
-            f"none — use kv='dense' with prefill_chunk=0")
-
-
-class DenseKV(KVCacheManager):
-    """Today's layout behind the manager interface (bit-identical refactor).
-
-    Slot state and install scatter are the jitted functions the engine
-    built inline before the refactor; the vmapped ``decode_slots`` launch
-    runs :func:`_decode_slot_fn`.
-    Chunked prefill stages chunks in a private batch-1 state and installs it
-    on completion, so the stacked slot states see exactly one update per
-    admission either way.
-    """
-
-    name = "dense"
-
-    def __init__(self, engine: "Server", prefill_chunk: int = 0) -> None:
-        self.engine = engine
-        self.chunk = max(0, int(prefill_chunk))
-        if self.chunk:
-            _require_extend(engine.model, "chunked prefill")
-        one = engine.model.init_decode_state(1, engine.max_seq)
-        self._slots = jax.tree_util.tree_map(
-            lambda x: jnp.stack([x] * engine.B), one)
-        self._nxt = jnp.zeros((engine.B, 1, 1), jnp.int32)
-        self._decode_slots = engine.tracker.wrap(
-            jax.jit(jax.vmap(_decode_slot_fn(engine.model, engine.T),
-                             in_axes=(None, 0, 0))),
-            "decode_slots")
-        # scatter one admitted request's prefilled state into its slot
-        self._install = jax.jit(
-            lambda full, part, i: jax.tree_util.tree_map(
-                lambda f, o: jax.lax.dynamic_update_index_in_dim(f, o, i, 0),
-                full, part))
-        self._extend = None
-        if self.chunk:
-            self._extend = engine.tracker.wrap(
-                jax.jit(engine.model.prefill_extend), "prefill_extend")
-        self._pending: Dict[int, Dict[str, Any]] = {}
-        self.counters = _LaunchCounters(_decode_body(engine.model))
-
-    # -- prefill -----------------------------------------------------------
-    def begin(self, slot: int, tix: "RequestTicket") -> bool:
-        prompt = np.asarray(tix.request.prompt, np.int32)
-        chunked = bool(self.chunk) and len(prompt) > self.chunk
-        self._pending[slot] = {"tix": tix, "prompt": prompt, "pos": 0,
-                               "chunked": chunked, "state": None}
-        if chunked:
-            self.counters.chunked_prompts += 1
-        return True
-
-    def prefill_step(self, slot: int) -> Optional[int]:
-        p = self._pending[slot]
-        tix, prompt = p["tix"], p["prompt"]
-        eng = self.engine
-        if not p["chunked"]:
-            with eng.session.span("serve.prefill", uid=tix.uid,
-                                  prompt_len=int(len(prompt))):
-                state, logits = eng._prefill(eng.params, jnp.asarray(
-                    prompt[None, :]))
-            self.counters.prefill_launches += 1
-            self.counters.prefill_tokens += len(prompt)
-            tix.n_prefill_launches += 1
-            return self._complete(slot, state, logits)
-        if p["state"] is None:
-            p["state"] = eng.model.init_decode_state(1, eng.max_seq)
-        pos = p["pos"]
-        c = min(self.chunk, len(prompt) - pos)
-        pairs = self.counters.count_extend(pos, c, eng.max_seq)
-        with eng.session.span("serve.prefill_chunk", uid=tix.uid, start=pos,
-                              size=c, prompt_len=int(len(prompt)), **pairs):
-            p["state"], logits = self._extend(
-                eng.params, p["state"], jnp.asarray(prompt[None, pos:pos + c]))
-        p["pos"] = pos + c
-        self.counters.prefill_launches += 1
-        self.counters.prefill_chunk_launches += 1
-        self.counters.prefill_tokens += c
-        tix.n_prefill_launches += 1
-        if p["pos"] < len(prompt):
-            return None
-        return self._complete(slot, p["state"], logits)
-
-    def _complete(self, slot: int, state, logits) -> int:
-        with self.engine.session.span("serve.prefill.readback"):
-            tok0 = int(jnp.argmax(logits[0, -1, :]))
-        self._slots = self._install(self._slots, state, np.int32(slot))
-        self._nxt = self._nxt.at[slot, 0, 0].set(tok0)
-        del self._pending[slot]
-        return tok0
-
-    # -- decode ------------------------------------------------------------
-    def decode(self) -> np.ndarray:
-        self._slots, toks, self._nxt = self._decode_slots(
-            self.engine.params, self._slots, self._nxt)
-        self.counters.decode_launches += 1
-        with self.engine.session.span("serve.decode.readback"):
-            return np.asarray(toks)                  # [B, T] host sync
-
-    def release(self, slot: int) -> None:
-        self._pending.pop(slot, None)
-
-    def stats(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = {"backend": self.name,
-                               "prefill_chunk": self.chunk}
-        out.update(self.counters.to_dict())
-        return out
+            f"the continuous-batching engine requires a model with a "
+            f"prefill_extend() decode-state extension (transformer-family); "
+            f"{type(model).__name__} has none")
 
 
 def _hash_page(parent: bytes, tokens: np.ndarray) -> bytes:
@@ -317,8 +70,17 @@ def _hash_page(parent: bytes, tokens: np.ndarray) -> bytes:
     return h.digest()
 
 
-class PagedKV(KVCacheManager):
+class PagedKV:
     """Fixed-size pages + block tables + shared-prefix page reuse.
+
+    Lifecycle per request: :meth:`begin` claims pages for a slot (False
+    only on unrecoverable page exhaustion -> the engine evicts with
+    ``reason="kv_pages"``); :meth:`prefill_step` advances the slot's
+    prefill by one launch and returns the first generated token once the
+    prompt is fully in cache; :meth:`reserve_decode` grows block tables
+    ahead of a decode launch (returning victims when the pool cannot);
+    :meth:`decode` runs the one launch over all slots; :meth:`release`
+    returns the slot's pages.
 
     Geometry: pool ``[L, 1 + pages, page_tokens, Hkv, hd]`` (page 0 is
     scratch), block tables ``[B, max_seq // page_tokens]`` host-side.  Pages
@@ -341,10 +103,10 @@ class PagedKV(KVCacheManager):
     def __init__(self, engine: "Server", page_tokens: int = 16,
                  pages: Optional[int] = None,
                  prefill_chunk: int = 0) -> None:
-        _require_extend(engine.model, "kv='paged'")
+        _require_extend(engine.model)
         self.engine = engine
         self.pt = int(page_tokens)
-        self.chunk = max(0, int(prefill_chunk))
+        self.chunk = max(0, int(prefill_chunk))   # 0 = whole-prompt prefill
         self.n_blk, default_pages = kv_geometry(
             engine.max_seq, self.pt, engine.B)
         self.pages = int(pages) if pages is not None else default_pages
@@ -376,46 +138,39 @@ class PagedKV(KVCacheManager):
         self._chain: Dict[int, List[bytes]] = {}      # slot -> page keys
         self._pending: Dict[int, Dict[str, Any]] = {}
 
-        self.in_place = _attends_pages_in_place(self.k_pool)
-        self.counters = _LaunchCounters("pages" if self.in_place else "rows")
+        # launch accounting (feeds loadtest --json and the chip benchmark)
         self.pages_allocated = 0                      # cumulative fresh
         self.pages_reused = 0                         # prefix-hit attaches
         self.prefix_hits = 0                          # requests that hit
         self.prefix_hit_tokens = 0
         self.pages_peak = 0
+        self.decode_launches = 0
+        self.prefill_launches = 0        # all prefill/extend launches
+        self.prefill_chunk_launches = 0  # the subset that were chunk ticks
+        self.prefill_tokens = 0          # prompt tokens actually pushed
+        self.chunked_prompts = 0         # prompts that needed >1 launch
+        # query-key pairs of the extend launches: every one the attention
+        # scores (each query against the whole cache buffer) and those the
+        # causal mask keeps (each query against itself and what precedes it)
+        self.extend_scored_positions = 0
+        self.extend_live_positions = 0
+        # pages of the decode launches: those the attention reads and those
+        # the active slots' block tables hold
+        self.decode_pages_read = 0
+        self.decode_pages_table = 0
 
         T, S = engine.T, engine.max_seq
         L, Hk, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
 
-        # The named scopes label the programs' ops in a profiler trace:
-        # the table gather, the model, the scatter back.  The scatter's
-        # start clamps exactly like the dense dynamic_update_slice when a
-        # finishing slot overruns.  Rows come back [L, B, T, Hkv, hd].
-        def gathered_rows(params, k_pool, v_pool, tables, lengths, nxt):
-            def slot_fn(table, length, tok):
-                with jax.named_scope("kv_gather"):
-                    k = gather_block_table(k_pool, table)
-                    v = gather_block_table(v_pool, table)
-                with jax.named_scope("decode_slot"):
-                    toks, tok, rk, rv = model.decode_rows(
-                        params, k, v, length, tok, T)
-                return toks, tok, rk, rv
-
-            toks, nxts, rows_k, rows_v = jax.vmap(
-                slot_fn, out_axes=(0, 0, 1, 1))(tables, lengths, nxt)
-            return toks, nxts, rows_k, rows_v
-
-        def rows_in_place(params, k_pool, v_pool, tables, lengths, nxt):
+        # The named scopes label the programs' ops in a profiler trace: the
+        # model, the table gather, the scatter back.  The decode scatter's
+        # start clamps to S - T when a finishing slot overruns, as the
+        # decode body's window does.  Rows come back [L, B, T, Hkv, hd].
+        def decode_paged(params, k_pool, v_pool, tables, lengths, nxt):
             with jax.named_scope("decode_pages"):
                 toks, nxts, rows_k, rows_v = model.decode_rows_paged(
                     params, k_pool, v_pool, tables, lengths, nxt[:, 0], T)
-            return toks, nxts[:, None], rows_k, rows_v
-
-        rows_fn = rows_in_place if self.in_place else gathered_rows
-
-        def decode_paged(params, k_pool, v_pool, tables, lengths, nxt):
-            toks, nxts, rows_k, rows_v = rows_fn(
-                params, k_pool, v_pool, tables, lengths, nxt)
+                nxts = nxts[:, None]
 
             def body(b, pools):
                 kp, vp = pools
@@ -535,7 +290,7 @@ class PagedKV(KVCacheManager):
         self._pending[slot] = {"tix": tix, "prompt": prompt, "pos": start,
                                "chunked": chunked}
         if chunked:
-            self.counters.chunked_prompts += 1
+            self.chunked_prompts += 1
         if shared:
             self.prefix_hits += 1
             self.pages_reused += len(shared)
@@ -550,6 +305,16 @@ class PagedKV(KVCacheManager):
                 pages=len(got), payload_bytes=len(got) * self.page_bytes)
         return True
 
+    def _count_extend(self, start: int, size: int) -> Dict[str, int]:
+        """Count one extend launch of ``size`` prompt positions from
+        ``start`` against the slot's whole cache buffer; returns the
+        launch's ``scored`` and ``live`` pairs, for its span."""
+        scored = size * self.n_blk * self.pt
+        live = size * start + size * (size + 1) // 2
+        self.extend_scored_positions += scored
+        self.extend_live_positions += live
+        return {"scored": scored, "live": live}
+
     def prefill_step(self, slot: int) -> Optional[int]:
         p = self._pending[slot]
         tix, prompt, pos = p["tix"], p["prompt"], p["pos"]
@@ -559,7 +324,7 @@ class PagedKV(KVCacheManager):
         c = min(self.chunk, remaining) if p["chunked"] else remaining
         span_name = "serve.prefill_chunk" if p["chunked"] else "serve.prefill"
         table = jnp.asarray(self.tables[slot])
-        pairs = self.counters.count_extend(pos, c, self.n_blk * self.pt)
+        pairs = self._count_extend(pos, c)
         with eng.session.span(span_name, uid=tix.uid, start=pos, size=c,
                               prompt_len=plen, **pairs):
             self.k_pool, self.v_pool, logits = self._extend(
@@ -567,10 +332,10 @@ class PagedKV(KVCacheManager):
                 jnp.asarray(pos, jnp.int32), jnp.asarray(prompt[None,
                                                                 pos:pos + c]))
         p["pos"] = pos + c
-        self.counters.prefill_launches += 1
+        self.prefill_launches += 1
         if p["chunked"]:
-            self.counters.prefill_chunk_launches += 1
-        self.counters.prefill_tokens += c
+            self.prefill_chunk_launches += 1
+        self.prefill_tokens += c
         tix.n_prefill_launches += 1
         if p["pos"] < plen:
             return None
@@ -643,17 +408,13 @@ class PagedKV(KVCacheManager):
         self.k_pool, self.v_pool, toks, self._nxt = self._decode_slots(
             self.engine.params, self.k_pool, self.v_pool, tables, lengths,
             self._nxt)
-        self.counters.decode_launches += 1
-        # pages the attention reads: the kernel the live ones below each
-        # slot's window start, the gather every table row
+        self.decode_launches += 1
+        # pages the kernel reads: the live ones below each slot's window
+        # start, against every row of the active slots' tables
         active = self.lengths[self.ready]
-        table = len(active) * self.n_blk
-        read = table
-        if self.in_place:
-            start = np.minimum(active, self.engine.max_seq - self.engine.T)
-            read = int(np.sum(-(-start // self.pt)))
-        self.counters.decode_pages_table += table
-        self.counters.decode_pages_read += read
+        start = np.minimum(active, self.engine.max_seq - self.engine.T)
+        self.decode_pages_table += len(active) * self.n_blk
+        self.decode_pages_read += int(np.sum(-(-start // self.pt)))
         with sess.span("serve.decode.readback"):
             blocks = np.asarray(toks)                # [B, T] host sync
         self.lengths[self.ready] += self.engine.T
@@ -677,7 +438,7 @@ class PagedKV(KVCacheManager):
         self._pending.pop(slot, None)
 
     def stats(self) -> Dict[str, Any]:
-        out: Dict[str, Any] = {
+        return {
             "backend": self.name,
             "prefill_chunk": self.chunk,
             "page_tokens": self.pt,
@@ -689,18 +450,14 @@ class PagedKV(KVCacheManager):
             "pages_cached": len(self._cached),
             "prefix_hits": self.prefix_hits,
             "prefix_hit_tokens": self.prefix_hit_tokens,
+            "decode_launches": self.decode_launches,
+            "prefill_launches": self.prefill_launches,
+            "prefill_chunk_launches": self.prefill_chunk_launches,
+            "prefill_tokens": self.prefill_tokens,
+            "prefill_payload_bytes": 4 * self.prefill_tokens,
+            "chunked_prompts": self.chunked_prompts,
+            "extend_scored_positions": self.extend_scored_positions,
+            "extend_live_positions": self.extend_live_positions,
+            "decode_pages_read": self.decode_pages_read,
+            "decode_pages_table": self.decode_pages_table,
         }
-        out.update(self.counters.to_dict())
-        return out
-
-
-def make_kv(engine: "Server", kind: str = "dense",
-            page_tokens: int = 16, pages: Optional[int] = None,
-            prefill_chunk: int = 0) -> KVCacheManager:
-    if kind == "dense":
-        return DenseKV(engine, prefill_chunk=prefill_chunk)
-    if kind == "paged":
-        return PagedKV(engine, page_tokens=page_tokens, pages=pages,
-                       prefill_chunk=prefill_chunk)
-    raise ValueError(f"unknown kv backend {kind!r}; "
-                     f"expected one of {KV_BACKENDS}")

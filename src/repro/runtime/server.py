@@ -13,12 +13,11 @@ Two serving surfaces share one model/params/session:
   requests *join while it runs* (and leave mid-stream) without ever
   recompiling the graph-launched multi-token decode.
 
-The continuous engine keeps one decode state **per slot** (each slot is a
-full batch-1 state pytree, stacked on a fresh leading axis and driven by a
-``jax.vmap`` over slots).  Each slot therefore carries its own cache length
-and its own greedy chain — a request's tokens are *independent of batch
-composition and join time*, which is what makes continuous-batching output
-exactly equal to a one-shot ``serve()`` of the same request.
+The continuous engine keeps its KV cache in pages
+(:class:`~repro.runtime.kv.PagedKV`): each slot has its own block table,
+cache length and greedy chain, so a request's tokens are *independent of
+batch composition and join time*.  The one-shot ``serve()`` of the same
+request is the engine's oracle: its tokens are what the engine must emit.
 """
 from __future__ import annotations
 
@@ -193,13 +192,13 @@ class ContinuousBatchingServer(Server):
     decode never changes shape (and never recompiles) across join/leave
     boundaries.
 
-    Per-request state: slot ``i`` holds a complete batch-1 decode-state
-    pytree (own KV cache, own cache ``length``); the engine stacks all
-    ``batch_size`` slot states on a new leading axis and decodes them with
-    one ``vmap``-ed launch.  Prefill runs per admitted request at its exact
-    prompt length (compiled once per distinct length), so a request's
-    greedy chain is bit-identical to ``Server.serve([request])`` no matter
-    when it joined or who shared the batch.
+    Per-request state: slot ``i`` holds a block table into the
+    :class:`~repro.runtime.kv.PagedKV` page pools and its own cache length;
+    one decode launch advances every slot by ``tokens_per_launch`` tokens.
+    Prefill runs per admitted request (whole, or in ``prefill_chunk``
+    pieces), so a request's greedy chain follows ``Server.serve([request])``
+    no matter when it joined or who shared the batch.  The model must offer
+    ``prefill_extend``; one without it is refused at construction.
 
     Lifecycle events land on the session timeline as ``progress`` events
     (``serve.submit/admit/finish/evict/reject``); a finish event carries
@@ -220,11 +219,14 @@ class ContinuousBatchingServer(Server):
                  session: Optional[TraceSession] = None,
                  max_pending: int = 256,
                  admission: str = "reject",
-                 kv: str = "dense",
+                 kv: str = "paged",
                  kv_page_tokens: Optional[int] = None,
                  kv_pages: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  sched: str = "fifo") -> None:
+        if kv != "paged":
+            raise ValueError(f"unknown kv manager {kv!r}; the engine keeps "
+                             f"its KV cache in pages (kv='paged')")
         super().__init__(cfg, batch_size, max_seq,
                          tokens_per_launch=tokens_per_launch, seed=seed,
                          session=session)
@@ -248,27 +250,23 @@ class ContinuousBatchingServer(Server):
         self.session.add_sink(self.live)
         self._live_server: Optional[Any] = None
 
-        # KV backend: dense (stacked per-slot states, the PR-7 layout) or
-        # paged (global page pool + block tables + shared-prefix reuse).
-        # Unset knobs fall back to the tuned policy for this config.
-        if kv == "paged" and kv_page_tokens is None:
+        # page pool + block tables + shared-prefix reuse.  Unset knobs fall
+        # back to the tuned policy for this config; explicit invalid values
+        # (e.g. 0) reach kv_geometry's validation instead of being coerced
+        if kv_page_tokens is None:
             kv_page_tokens = int(self.policy.knob("kv_page_tokens", 16)
                                  if self.policy else 16)
         if prefill_chunk is None:
             prefill_chunk = int(self.policy.knob("prefill_chunk", 0)
                                 if self.policy else 0)
-        from .kv import make_kv
-        # None -> default; explicit invalid values (e.g. 0) must reach
-        # kv_geometry's validation instead of being silently coerced
-        self.kv = make_kv(
-            self, kv,
-            page_tokens=16 if kv_page_tokens is None else kv_page_tokens,
-            pages=kv_pages, prefill_chunk=prefill_chunk)
+        from .kv import PagedKV
+        self.kv = PagedKV(self, page_tokens=kv_page_tokens, pages=kv_pages,
+                          prefill_chunk=prefill_chunk)
 
     @property
     def _decode_slots(self):
-        """The backend's vmapped decode launch (tests inspect its compile
-        cache to prove shape stability across churn)."""
+        """The KV manager's decode launch (tests inspect its compile cache
+        to prove shape stability across churn)."""
         return self.kv._decode_slots
 
     # -- intake (any thread) ----------------------------------------------
@@ -352,7 +350,7 @@ class ContinuousBatchingServer(Server):
     def _end_request_span(self, tix: RequestTicket) -> None:
         """Close a request's causal span with its declared attribution.
 
-        The vmapped decode launch is shared by every active slot, so this
+        The decode launch is shared by every active slot, so this
         request's share of the command stream can't be read off stamped
         events — it is *declared* here instead: one doorbell per decode
         launch the request rode (``n_launches``) plus its prefill, and
@@ -379,8 +377,8 @@ class ContinuousBatchingServer(Server):
     def _admit(self) -> int:
         """Move queued tickets into free slots.
 
-        Whole-prompt admission (no chunking) prefills synchronously here —
-        the pre-refactor behavior.  Prompts longer than the backend's
+        Whole-prompt admission (no chunking) prefills synchronously here.
+        Prompts longer than the KV manager's
         ``prefill_chunk`` only *start* here; :meth:`_prefill_tick` advances
         them one bounded launch per scheduler iteration so active slots
         keep decoding underneath.
@@ -490,9 +488,9 @@ class ContinuousBatchingServer(Server):
                      if tix is not None and slot not in self._prefilling]
         if not decodable:
             return admitted, self.n_active, 0
-        # paged backend: grow block tables for the coming T writes; slots
-        # the pool cannot serve are evicted (reason="kv_pages") and their
-        # freed pages immediately retried for the survivors
+        # grow block tables for the coming T writes; slots the pool cannot
+        # serve are evicted (reason="kv_pages") and their freed pages
+        # immediately retried for the survivors
         while True:
             victims = self.kv.reserve_decode(decodable)
             if not victims:
@@ -570,7 +568,7 @@ class ContinuousBatchingServer(Server):
             "tokens_per_doorbell": new_tokens / max(1, doorbells),
             "tokens_per_s": new_tokens / max(wall, 1e-9),
             "trace_events": self.session.n_events - ev0,
-            # backend memory-path accounting (pages, prefix hits, prefill
+            # KV memory-path accounting (pages, prefix hits, prefill
             # launches/bytes) — engine-lifetime totals, not per-run deltas
             "kv": self.kv.stats(),
         }

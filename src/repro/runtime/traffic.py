@@ -46,7 +46,7 @@ class TrafficSpec:
     seed: int = 0
     #: tokens of seeded prefix shared by every prompt (0 = independent
     #: prompts); models system-prompt traffic, the regime where the paged
-    #: KV backend's prefix-page reuse pays off
+    #: KV manager's prefix-page reuse pays off
     prefix_len: int = 0
 
     def to_dict(self) -> Dict[str, Any]:

@@ -24,7 +24,7 @@ def main(argv=None) -> None:
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--workloads", default="dma,serve,train",
                     help="comma-separated subset of dma,serve,train,kv "
-                         "(kv tunes the paged backend's kv_page_tokens + "
+                         "(kv tunes the paged KV manager's kv_page_tokens + "
                          "prefill_chunk; opt-in)")
     ap.add_argument("--policy-dir", default=None)
     ap.add_argument("--batch", type=int, default=2)

@@ -11,7 +11,7 @@ run under a fresh :class:`~repro.core.TraceSession`:
   ``steps_per_launch``, the graph capture granularity of the multi-step
   launcher);
 * ``kv``    — shared-prefix continuous-batching traffic on the paged KV
-  backend (knobs: ``kv_page_tokens``, ``prefill_chunk``); opt-in via
+  manager (knobs: ``kv_page_tokens``, ``prefill_chunk``); opt-in via
   ``--workloads``.
 
 Every workload warms up first (compile + first dispatch) and measures only
@@ -190,7 +190,7 @@ class CandidateEvaluator:
         return m
 
     def _measure_kv(self, knobs: Dict[str, Any]) -> Metrics:
-        """Score page size + prefill chunking on the paged backend under
+        """Score page size + prefill chunking on the paged KV manager under
         shared-prefix traffic — the regime where both knobs matter: page
         granularity sets how much of the common prefix is reusable, and
         the chunk bound trades prefill latency against decode stalls."""
@@ -209,7 +209,7 @@ class CandidateEvaluator:
             eng = ContinuousBatchingServer(
                 self.cfg, batch_size=spec.batch, max_seq=spec.max_seq,
                 tokens_per_launch=4,
-                seed=spec.seed, session=sess, kv="paged",
+                seed=spec.seed, session=sess,
                 kv_page_tokens=int(knobs["kv_page_tokens"]),
                 prefill_chunk=int(knobs["prefill_chunk"]))
             # warm: compiles the paged decode + extend kernels

@@ -128,11 +128,9 @@ def test_paged_decode_launch_reads_pages_in_place(one_chip, monkeypatch, arch,
     slot's whole [L, S, Hkv, hd] cache besides the weights and the page
     pools themselves (the undonated pools' copy and the scatter into it).
     The gather of every slot's block table made such a cache per slot."""
-    from repro.runtime import kv as kv_mod
     from repro.runtime.server import ContinuousBatchingServer
 
-    # the host is a CPU: steer the path and the kernel to the chip's
-    monkeypatch.setattr(kv_mod, "_attends_pages_in_place", lambda pool: True)
+    # the host is a CPU: compile the kernel for the chip, not interpreted
     monkeypatch.setattr(paged_ops, "interpret_mode", lambda: False)
     cfg = dataclasses.replace(ARCHS[arch], n_layers=layers,
                               tie_embeddings=False)
@@ -141,13 +139,12 @@ def test_paged_decode_launch_reads_pages_in_place(one_chip, monkeypatch, arch,
     def build():
         eng = ContinuousBatchingServer(
             cfg, batch_size=slots, max_seq=max_seq, tokens_per_launch=4,
-            seed=0, kv="paged", kv_page_tokens=64, prefill_chunk=512)
+            seed=0, kv_page_tokens=64, prefill_chunk=512)
         box["kv"] = eng.kv
         return eng.params, eng.kv.k_pool
 
     params, pool = jax.eval_shape(build)
     kv = box["kv"]
-    assert kv.counters.decode_body == "pages"
 
     def on_chip(tree):
         return jax.tree_util.tree_map(

@@ -1,10 +1,10 @@
-"""Paged KV backend, chunked prefill, and pluggable scheduling.
+"""Paged KV manager, chunked prefill, and pluggable scheduling.
 
 The serving memory-path invariants:
 
-* backend equivalence — the paged backend (block tables, page pool) and
-  chunked prefill must produce *bit-identical* token streams to the dense
-  whole-prompt path on the same seeded replay;
+* equivalence — the engine (block tables, page pool, chunked prefill,
+  prefix reuse) must produce *bit-identical* token streams to one-shot
+  ``Server.serve`` of each request, the contiguous-cache oracle;
 * capacity honesty — a too-small page pool evicts with
   ``reason="kv_pages"`` instead of corrupting neighbours;
 * prefix sharing — prompts with a common prefix reuse the pages holding
@@ -22,11 +22,10 @@ import pytest
 from repro.configs import SMOKE_ARCHS
 from repro.configs.shapes import SERVE_SHAPES, kv_geometry
 from repro.core.session import SPAN_EVENT, TraceSession
-from repro.runtime.kv import KV_BACKENDS, make_kv
 from repro.runtime.scheduler import (AdmissionQueue, FairSharePolicy,
                                      PriorityPolicy, RequestTicket,
                                      make_policy)
-from repro.runtime.server import ContinuousBatchingServer, Request
+from repro.runtime.server import ContinuousBatchingServer, Request, Server
 from repro.runtime.traffic import TrafficSpec, generate, replay
 
 CFG = SMOKE_ARCHS["gemma-2b"]
@@ -53,10 +52,23 @@ def _replay(sink=None, **kw):
     return {t.uid: list(t.tokens) for t in tickets}, metrics, eng
 
 
+def _oneshot(requests, max_seq=64):
+    """Each request's tokens from one-shot ``Server.serve`` with the
+    engine's weights (same seed): the contiguous-cache oracle."""
+    solo = Server(CFG, batch_size=1, max_seq=max_seq, tokens_per_launch=1,
+                  seed=0)
+    out = {}
+    for r in requests:
+        ref = Request(r.uid, r.prompt, max_new_tokens=r.max_new_tokens)
+        solo.serve([ref])
+        out[r.uid] = ref.tokens
+    return out
+
+
 @pytest.fixture(scope="module")
-def dense_ref():
-    toks, metrics, _ = _replay()
-    return toks, metrics
+def oneshot_ref():
+    """One-shot tokens of every request in ``SPEC``."""
+    return _oneshot([a.request for a in generate(SPEC, CFG.vocab_size)])
 
 
 # -- geometry ---------------------------------------------------------------
@@ -74,32 +86,52 @@ def test_kv_geometry_and_serve_shapes():
 
 
 def test_make_kv_rejects_unknown_backend():
-    eng = object.__new__(ContinuousBatchingServer)   # no engine needed
-    with pytest.raises(ValueError, match="backend"):
-        make_kv(eng, "compressed")
-    assert KV_BACKENDS == ("dense", "paged")
+    """The engine keeps its KV cache in pages: any other ``kv`` is
+    refused before anything is built."""
+    for kind in ("dense", "compressed"):
+        with pytest.raises(ValueError, match="kv manager"):
+            ContinuousBatchingServer(CFG, batch_size=1, max_seq=64, kv=kind)
 
 
-# -- backend equivalence ----------------------------------------------------
+@pytest.mark.parametrize("arch,serves", [
+    ("mamba2-780m", True), ("jamba-v0.1-52b", True),
+    ("whisper-medium", False),
+], ids=["mamba2-780m", "jamba-v0.1-52b", "whisper-medium"])
+def test_engine_rejects_model_without_extend(arch, serves):
+    """A model without ``prefill_extend`` cannot fill pages, so the engine
+    refuses it at construction; one-shot ``Server.serve`` still runs the
+    recurrent and hybrid models (whose prefill takes the prompt only)."""
+    cfg = SMOKE_ARCHS[arch]
+    with pytest.raises(ValueError, match="prefill_extend"):
+        ContinuousBatchingServer(cfg, batch_size=1, max_seq=32,
+                                 tokens_per_launch=2, seed=0)
+    if serves:
+        srv = Server(cfg, batch_size=1, max_seq=32, tokens_per_launch=2,
+                     seed=0)
+        req = Request(0, np.arange(1, 6, dtype=np.int32), max_new_tokens=3)
+        srv.serve([req])
+        assert len(req.tokens) == 3
 
-def test_paged_tokens_bit_identical_to_dense(dense_ref):
-    toks, metrics, _ = _replay(kv="paged", kv_page_tokens=8)
-    assert toks == dense_ref[0]
+
+# -- equivalence ------------------------------------------------------------
+
+def test_paged_tokens_bit_identical_to_dense(oneshot_ref):
+    """The engine's tokens equal one-shot serving's (a contiguous cache)."""
+    toks, metrics, _ = _replay(kv_page_tokens=8)
+    assert toks == oneshot_ref
     assert metrics["kv"]["backend"] == "paged"
     # default pool holds every slot fully grown: exhaustion impossible
     assert metrics["kv"]["pages_total"] == 4 * (64 // 8)
-    assert metrics["evicted"] == dense_ref[1]["evicted"]
+    assert metrics["evicted"] == 0
 
 
-def test_chunked_prefill_bit_identical_both_backends(dense_ref):
-    d_toks, d_m, _ = _replay(prefill_chunk=4)
-    p_toks, p_m, _ = _replay(kv="paged", kv_page_tokens=8, prefill_chunk=4)
-    assert d_toks == dense_ref[0]
-    assert p_toks == dense_ref[0]
+def test_chunked_prefill_bit_identical_both_backends(oneshot_ref):
+    """Chunked prefill leaves the tokens of one-shot serving unchanged."""
+    p_toks, p_m, _ = _replay(kv_page_tokens=8, prefill_chunk=4)
+    assert p_toks == oneshot_ref
     # prompts longer than the chunk really went through the chunked path
-    assert d_m["kv"]["chunked_prompts"] > 0
     assert p_m["kv"]["chunked_prompts"] > 0
-    assert d_m["kv"]["prefill_chunk_launches"] > 0
+    assert p_m["kv"]["prefill_chunk_launches"] > 0
 
 
 # -- page exhaustion --------------------------------------------------------
@@ -107,7 +139,7 @@ def test_chunked_prefill_bit_identical_both_backends(dense_ref):
 def test_page_exhaustion_evicts_with_kv_pages_reason():
     eng = ContinuousBatchingServer(CFG, batch_size=4, max_seq=64,
                                    tokens_per_launch=2, seed=0,
-                                   kv="paged", kv_page_tokens=8, kv_pages=9)
+                                   kv_page_tokens=8, kv_pages=9)
     rng = np.random.default_rng(0)
     tix = [eng.submit(Request(uid=i,
                               prompt=rng.integers(0, CFG.vocab_size, 20)
@@ -124,7 +156,7 @@ def test_page_exhaustion_evicts_with_kv_pages_reason():
 def test_pool_smaller_than_one_slot_rejected():
     with pytest.raises(ValueError, match="full slot"):
         ContinuousBatchingServer(CFG, batch_size=4, max_seq=64,
-                                 tokens_per_launch=2, seed=0, kv="paged",
+                                 tokens_per_launch=2, seed=0,
                                  kv_page_tokens=8, kv_pages=4)
 
 
@@ -134,7 +166,7 @@ def test_explicit_zero_page_tokens_rejected():
     with pytest.raises(ValueError, match="positive"):
         ContinuousBatchingServer(CFG, batch_size=1, max_seq=64,
                                  tokens_per_launch=2, seed=0,
-                                 kv="paged", kv_page_tokens=0)
+                                 kv_page_tokens=0)
 
 
 # -- shared-prefix page reuse -----------------------------------------------
@@ -162,15 +194,16 @@ def _run_shared(sink=None, **kw):
 
 def test_shared_prefix_reuses_pages_and_shrinks_prefill():
     sink = ListSink()
-    d_toks, d_m = _run_shared(prefill_chunk=8)
-    p_toks, p_m = _run_shared(sink, kv="paged", kv_page_tokens=8,
-                              prefill_chunk=8)
+    # 32-token pages hold no whole page of the 24-token prefix: no reuse
+    d_toks, d_m = _run_shared(kv_page_tokens=32, prefill_chunk=8)
+    p_toks, p_m = _run_shared(sink, kv_page_tokens=8, prefill_chunk=8)
+    assert d_m["kv"]["prefix_hits"] == 0
     assert p_toks == d_toks                       # reuse never changes bits
     kv = p_m["kv"]
     assert kv["prefix_hits"] > 0
     assert kv["pages_reused"] > 0
-    # the satellite acceptance pair: strictly fewer prefill doorbells AND
-    # strictly fewer prefill payload bytes than dense on the same workload
+    # strictly fewer prefill doorbells AND strictly fewer prefill payload
+    # bytes than without reuse on the same workload
     assert kv["prefill_launches"] < d_m["kv"]["prefill_launches"]
     assert kv["prefill_payload_bytes"] < d_m["kv"]["prefill_payload_bytes"]
     names = [e.name for e in sink.events if e.kind == "progress"]
@@ -187,7 +220,7 @@ def test_shared_prefix_pinned_under_pool_pressure():
     the shared prefix."""
     eng = ContinuousBatchingServer(CFG, batch_size=1, max_seq=64,
                                    tokens_per_launch=2, seed=0,
-                                   kv="paged", kv_page_tokens=8, kv_pages=8)
+                                   kv_page_tokens=8, kv_pages=8)
     rng = np.random.default_rng(7)
     prefix = rng.integers(0, CFG.vocab_size, 24).astype(np.int32)
 
@@ -227,7 +260,7 @@ def test_shared_prefix_pinned_under_pool_pressure():
 def test_shared_prefix_under_pressure_tokens_match_dense():
     """End-to-end cover for the pin-before-allocate fix: prefix sharing and
     pool pressure *together* (each was covered separately before) must stay
-    bit-identical to dense."""
+    bit-identical to one-shot serving."""
     rng = np.random.default_rng(11)
     prefix = rng.integers(0, CFG.vocab_size, 24).astype(np.int32)
 
@@ -244,16 +277,12 @@ def test_shared_prefix_under_pressure_tokens_match_dense():
                 Request(uid=2, prompt=np.concatenate([prefix, mk(8)]),
                         max_new_tokens=4)]
 
-    def run(**kw):
-        eng = ContinuousBatchingServer(CFG, batch_size=1, max_seq=64,
-                                       tokens_per_launch=2, seed=0, **kw)
-        tix = [eng.submit(r) for r in reqs()]
-        eng.run(idle_timeout_s=0.0)
-        return {t.uid: list(t.tokens) for t in tix}, eng
-
-    d_toks, _ = run()
-    p_toks, eng = run(kv="paged", kv_page_tokens=8, kv_pages=8)
-    assert p_toks == d_toks
+    eng = ContinuousBatchingServer(CFG, batch_size=1, max_seq=64,
+                                   tokens_per_launch=2, seed=0,
+                                   kv_page_tokens=8, kv_pages=8)
+    tix = [eng.submit(r) for r in reqs()]
+    eng.run(idle_timeout_s=0.0)
+    assert {t.uid: list(t.tokens) for t in tix} == _oneshot(reqs())
     assert eng.kv.prefix_hits == 2            # both followers attached
     assert all(t.status in ("done",) for t in eng.tickets)
 
@@ -263,21 +292,18 @@ def test_no_registration_in_clamped_decode_write_zone():
     sharing: a slot finishing at the KV cap scatter-writes its clamped
     decode rows there, and registered pages must stay immutable once other
     requests attach (reachable with page_tokens < tokens_per_launch)."""
-    def run(**kw):
-        eng = ContinuousBatchingServer(CFG, batch_size=1, max_seq=64,
-                                       tokens_per_launch=8, seed=0, **kw)
-        rng = np.random.default_rng(21)
-        base = rng.integers(0, CFG.vocab_size, 60).astype(np.int32)
-        ext = rng.integers(0, CFG.vocab_size, 4).astype(np.int32)
-        tix = [eng.submit(Request(uid=0, prompt=base, max_new_tokens=5)),
-               eng.submit(Request(uid=1, prompt=np.concatenate([base, ext]),
-                                  max_new_tokens=1))]
-        eng.run(idle_timeout_s=0.0)
-        return {t.uid: list(t.tokens) for t in tix}, eng
-
-    d_toks, _ = run()
-    p_toks, eng = run(kv="paged", kv_page_tokens=4)
-    assert p_toks == d_toks
+    rng = np.random.default_rng(21)
+    base = rng.integers(0, CFG.vocab_size, 60).astype(np.int32)
+    ext = rng.integers(0, CFG.vocab_size, 4).astype(np.int32)
+    reqs = [Request(uid=0, prompt=base, max_new_tokens=5),
+            Request(uid=1, prompt=np.concatenate([base, ext]),
+                    max_new_tokens=1)]
+    eng = ContinuousBatchingServer(CFG, batch_size=1, max_seq=64,
+                                   tokens_per_launch=8, seed=0,
+                                   kv_page_tokens=4)
+    tix = [eng.submit(r) for r in reqs]
+    eng.run(idle_timeout_s=0.0)
+    assert {t.uid: list(t.tokens) for t in tix} == _oneshot(reqs)
     # A's 60-token prompt fully covers 15 pages, but page 14 spans
     # [56, 60) inside the clamp zone [56, 64) — only 14 get registered
     assert len(eng.kv._key_of) == 14
@@ -369,29 +395,39 @@ def test_chunked_prefill_keeps_decode_iters_flowing():
         f"two chunk launches with no decode iteration between: {pinned}")
 
 
-@pytest.mark.parametrize("chunk", [8, 0], ids=["chunked", "whole"])
-@pytest.mark.parametrize("kv", KV_BACKENDS)
-def test_extend_counters_match_closed_form(kv, chunk):
+C = 8
+
+
+@pytest.mark.parametrize("chunk,prompt_lens,launches", [
+    (C, [3 * C], [(0, C), (C, C), (2 * C, C)]),
+    (0, [3 * C], [(0, 3 * C)]),
+    # the second prompt attaches to the first's 16-token page: its first
+    # extend starts past the shared prefix
+    (C, [3 * C, 4 * C],
+     [(0, C), (C, C), (2 * C, C), (2 * C, C), (3 * C, C)]),
+    (C, [2 * C + 3], [(0, C), (C, C), (2 * C, 3)]),
+], ids=["paged-chunked", "paged-whole", "paged-prefix-hit",
+        "paged-short-last-chunk"])
+def test_extend_counters_match_closed_form(chunk, prompt_lens, launches):
     """kv.stats() and each prefill span count the query-key pairs an extend
     launch of C positions from ``start`` scores against an S-position cache
     (C·S) and those the causal mask keeps (C·start + C(C+1)/2), on a GQA
-    model; a dense whole-prompt prefill runs no extend and counts none."""
+    model.  The prompts are prefixes of one token sequence, served one
+    after another."""
     cfg = SMOKE_ARCHS["qwen3-8b"]
-    S, C = 64, 8
+    S = 64
     sink = ListSink()
     eng = ContinuousBatchingServer(
         cfg, batch_size=2, max_seq=S, tokens_per_launch=4, seed=0,
-        session=TraceSession(name="test_extend", sinks=[sink]), kv=kv,
+        session=TraceSession(name="test_extend", sinks=[sink]),
         kv_page_tokens=16, prefill_chunk=chunk)
-    prompt = np.random.default_rng(4).integers(0, cfg.vocab_size, 3 * C)
-    tix = eng.submit(Request(uid=0, prompt=prompt.astype(np.int32),
-                             max_new_tokens=4))
-    eng.run(idle_timeout_s=0.0)
-    assert tix.status == "done"
-    if chunk:
-        launches = [(0, C), (C, C), (2 * C, C)]
-    else:
-        launches = [(0, 3 * C)] if kv == "paged" else []
+    tokens = np.random.default_rng(4).integers(
+        0, cfg.vocab_size, max(prompt_lens)).astype(np.int32)
+    for uid, n in enumerate(prompt_lens):
+        tix = eng.submit(Request(uid=uid, prompt=tokens[:n],
+                                 max_new_tokens=4))
+        eng.run(idle_timeout_s=0.0)
+        assert tix.status == "done"
     want = [(c * S, c * a + c * (c + 1) // 2) for a, c in launches]
     st = eng.kv.stats()
     assert (st["extend_scored_positions"], st["extend_live_positions"]) == (

@@ -1,10 +1,9 @@
 """Continuous-batching engine: equivalence, admission/eviction, traffic.
 
-The load-bearing invariant: because every KV slot carries a complete
-batch-1 decode state (own cache length, own greedy chain), a request's
-tokens are independent of batch composition and join time — continuous
-batching must produce *exactly* the tokens a one-shot ``serve()`` of the
-same request would.
+The load-bearing invariant: because every KV slot carries its own block
+table, cache length and greedy chain, a request's tokens are independent of
+batch composition and join time — continuous batching must produce
+*exactly* the tokens a one-shot ``serve()`` of the same request would.
 """
 import threading
 import time
@@ -136,7 +135,8 @@ def test_admission_rejects_prompt_longer_than_max_seq(engine):
 
 def test_eviction_on_kv_overrun_truncates_to_capacity():
     eng = ContinuousBatchingServer(CFG, batch_size=2, max_seq=8,
-                                   tokens_per_launch=2, seed=1)
+                                   tokens_per_launch=2, seed=1,
+                                   kv_page_tokens=4)
     ok = eng.submit(mk_request(0, 4, 3))             # fits: cap=5
     greedy = eng.submit(mk_request(1, 6, 10))        # cap = 8-6+1 = 3
     out = eng.run(idle_timeout_s=0.0)

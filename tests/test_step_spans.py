@@ -28,12 +28,12 @@ class ListSink:
         self.events.append(event)
 
 
-def _engine(kv="paged"):
+def _engine():
     sink = ListSink()
     sess = TraceSession(name="test_step_spans", sinks=[sink])
     eng = ContinuousBatchingServer(CFG, batch_size=2, max_seq=64,
                                    tokens_per_launch=4, seed=0, session=sess,
-                                   kv=kv, kv_page_tokens=16)
+                                   kv_page_tokens=16)
     return eng, sink
 
 
@@ -51,10 +51,9 @@ def _span_paths(events):
 @pytest.mark.parametrize("kv,prefill,upload", [
     ("paged", "prefill_extend", ["serve.step/serve.decode_iter/"
                                  "serve.decode.upload"]),
-    ("dense", "prefill", []),
 ])
 def test_one_step_yields_the_span_tree(kv, prefill, upload):
-    eng, sink = _engine(kv)
+    eng, sink = _engine()
     eng.submit(_request(1))
     assert eng.step()
     assert _span_paths(sink.events) == [
