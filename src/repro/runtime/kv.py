@@ -185,8 +185,11 @@ class PagedKV:
                     0, tables.shape[0], body, (k_pool, v_pool))
             return k_pool, v_pool, toks, nxts
 
+        # Both launches own the pools (``donate_argnums``) so that the
+        # scatter writes the new rows in place: the arrays passed in are
+        # deleted, and callers must rebind the outputs.
         self._decode_slots = engine.tracker.wrap(
-            jax.jit(decode_paged), "decode_slots")
+            jax.jit(decode_paged, donate_argnums=(1, 2)), "decode_slots")
 
         def extend_paged(params, k_pool, v_pool, table, start, tokens):
             with jax.named_scope("kv_gather"):
@@ -207,7 +210,7 @@ class PagedKV:
             return k_pool, v_pool, logits
 
         self._extend = engine.tracker.wrap(
-            jax.jit(extend_paged), "prefill_extend")
+            jax.jit(extend_paged, donate_argnums=(1, 2)), "prefill_extend")
 
     # -- page accounting ---------------------------------------------------
     @property

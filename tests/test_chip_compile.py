@@ -13,6 +13,7 @@ depend on it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -115,47 +116,85 @@ def test_paged_attention_served_widths(one_chip, hq, hk, hd, L, slots, n_blk):
 
 
 _RESULT = re.compile(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]")
+_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w-]*)\(")
+_SHAPE = re.compile(r"\w+\[([\d,]*)\]")
 
-
-@pytest.mark.parametrize("arch,layers,slots,max_seq", [
+_SERVED = pytest.mark.parametrize("arch,layers,slots,max_seq", [
     ("deepseek-7b", 10, 7, 2048), ("qwen3-8b", 12, 6, 8384),
 ], ids=["deepseek-7b-l10", "qwen3-8b-l12"])
-def test_paged_decode_launch_reads_pages_in_place(one_chip, monkeypatch, arch,
-                                                  layers, slots, max_seq):
-    """The benchmark's decode launch (``decode_paged``) at its cells'
-    widths, slots, positions and 64-token pages, on the kernel path: the
-    kernel is in the program, and nothing it computes is as large as one
-    slot's whole [L, S, Hkv, hd] cache besides the weights and the page
-    pools themselves (the undonated pools' copy and the scatter into it).
-    The gather of every slot's block table made such a cache per slot."""
+
+
+def _size(dims: str) -> int:
+    size = 1
+    for d in dims.split(","):
+        if d:
+            size *= int(d)
+    return size
+
+
+@pytest.fixture(scope="module")
+def served_launch(one_chip):
+    """``(arch, layers, slots, max_seq, launch) -> (compiled, params,
+    pool)``: the engine's decode or extend launch at a cell's widths, slots
+    and 64-token pages, lowered for the described chip with the paged
+    kernel compiled, not interpreted.  The launch itself is lowered, not a
+    jit around it, which would drop its donation of the pools.  Compiled
+    once for the module."""
     from repro.runtime.server import ContinuousBatchingServer
-
-    # the host is a CPU: compile the kernel for the chip, not interpreted
-    monkeypatch.setattr(paged_ops, "interpret_mode", lambda: False)
-    cfg = dataclasses.replace(ARCHS[arch], n_layers=layers,
-                              tie_embeddings=False)
-    box = {}
-
-    def build():
-        eng = ContinuousBatchingServer(
-            cfg, batch_size=slots, max_seq=max_seq, tokens_per_launch=4,
-            seed=0, kv_page_tokens=64, prefill_chunk=512)
-        box["kv"] = eng.kv
-        return eng.params, eng.kv.k_pool
-
-    params, pool = jax.eval_shape(build)
-    kv = box["kv"]
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                            sharding=one_chip), tree)
 
-    i32 = jnp.int32
-    compiled = _compile(kv._decode_slots.__wrapped__, *on_chip((
-        params, pool, pool, jax.ShapeDtypeStruct((slots, kv.n_blk), i32),
-        jax.ShapeDtypeStruct((slots,), i32),
-        jax.ShapeDtypeStruct((slots, 1, 1), i32))))
+    @functools.cache
+    def get(arch, layers, slots, max_seq, launch):
+        cfg = dataclasses.replace(ARCHS[arch], n_layers=layers,
+                                  tie_embeddings=False)
+        box = {}
+
+        def build():
+            eng = ContinuousBatchingServer(
+                cfg, batch_size=slots, max_seq=max_seq, tokens_per_launch=4,
+                seed=0, kv_page_tokens=64, prefill_chunk=512)
+            box["kv"] = eng.kv
+            return eng.params, eng.kv.k_pool
+
+        params, pool = jax.eval_shape(build)
+        kv = box["kv"]
+        i32 = jnp.int32
+        if launch == "decode":
+            fn, rest = kv._decode_slots.__wrapped__, (
+                jax.ShapeDtypeStruct((slots, kv.n_blk), i32),
+                jax.ShapeDtypeStruct((slots,), i32),
+                jax.ShapeDtypeStruct((slots, 1, 1), i32))
+        else:
+            fn, rest = kv._extend.__wrapped__, (
+                jax.ShapeDtypeStruct((kv.n_blk,), i32),
+                jax.ShapeDtypeStruct((), i32),
+                jax.ShapeDtypeStruct((1, 512), i32))
+        # the host is a CPU: compile the kernel for the chip
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(paged_ops, "interpret_mode", lambda: False)
+            compiled = fn.lower(*on_chip((params, pool, pool) + rest)).compile()
+        return compiled, params, pool
+
+    return get
+
+
+@_SERVED
+def test_paged_decode_launch_reads_pages_in_place(served_launch, arch,
+                                                  layers, slots, max_seq):
+    """The benchmark's decode launch (``decode_paged``) at its cells'
+    widths, slots, positions and 64-token pages, on the kernel path: the
+    kernel is in the program, and nothing it computes is as large as one
+    slot's whole [L, S, Hkv, hd] cache besides the weights and the page
+    pools themselves (the donated pools, which the scatter updates in
+    place).  The gather of every slot's block table made such a cache per
+    slot."""
+    compiled, params, pool = served_launch(arch, layers, slots, max_seq,
+                                           "decode")
+    assert "tpu_custom_call" in compiled.as_text()
     L, P, pt, hk, hd = pool.shape
     slot_cache = L * max_seq * hk * hd
     allowed = {pool.shape, (L, P, pt * hk, hd)} | {
@@ -165,9 +204,26 @@ def test_paged_decode_launch_reads_pages_in_place(one_chip, monkeypatch, arch,
         m = _RESULT.match(line)
         if m:
             shape = tuple(int(d) for d in m.group(1).split(",") if d)
-            size = 1
-            for d in shape:
-                size *= d
-            if size >= slot_cache and shape not in allowed:
+            if _size(m.group(1)) >= slot_cache and shape not in allowed:
                 large.append(line.strip()[:160])
     assert not large, large
+
+
+@_SERVED
+@pytest.mark.parametrize("launch", ["decode", "extend"])
+def test_paged_launches_update_donated_pools_in_place(
+        served_launch, arch, layers, slots, max_seq, launch):
+    """Both launches are given the K and V page pools to own: the compiled
+    program aliases both to its outputs and copies neither.  Undonated,
+    each launch first copied both pools whole before scattering its new
+    rows (1.18 GB each for deepseek-7b-l10)."""
+    compiled, _, pool = served_launch(arch, layers, slots, max_seq, launch)
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * pool_bytes
+    copies = []
+    for line in compiled.as_text().splitlines():
+        m = _OP.match(line)
+        if m and m.group(2) in ("copy", "copy-start") and any(
+                _size(d) >= pool.size for d in _SHAPE.findall(m.group(1))):
+            copies.append(line.strip()[:160])
+    assert not copies, copies

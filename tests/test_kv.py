@@ -134,6 +134,39 @@ def test_chunked_prefill_bit_identical_both_backends(oneshot_ref):
     assert p_m["kv"]["prefill_chunk_launches"] > 0
 
 
+def test_launches_own_the_pools_they_are_given():
+    """The extend and decode launches are given the page pools to own: the
+    arrays passed in are deleted after each call (written in place, not
+    copied whole), the manager holds the outputs, which stay live, and the
+    doorbell still counts the pools among each launch's payload."""
+    eng = ContinuousBatchingServer(CFG, batch_size=2, max_seq=64,
+                                   tokens_per_launch=4, seed=0,
+                                   kv_page_tokens=8)
+    kv = eng.kv
+    given = {"extend": [], "decode": []}
+
+    def recorded(name, launch):
+        def call(params, k_pool, v_pool, *rest):
+            given[name].append((k_pool, v_pool))
+            return launch(params, k_pool, v_pool, *rest)
+        return call
+
+    kv._extend = recorded("extend", kv._extend)
+    kv._decode_slots = recorded("decode", kv._decode_slots)
+    prompt = np.random.default_rng(1).integers(
+        0, CFG.vocab_size, 12).astype(np.int32)
+    tix = eng.submit(Request(0, prompt, max_new_tokens=6))
+    eng.run(idle_timeout_s=0.0)
+    assert given["extend"] and given["decode"]
+    for k_pool, v_pool in given["extend"] + given["decode"]:
+        assert k_pool.is_deleted() and v_pool.is_deleted()
+    assert not kv.k_pool.is_deleted() and not kv.v_pool.is_deleted()
+    n = eng.tracker.count_for("decode_slots")
+    assert n == len(given["decode"])
+    assert eng.tracker.total_payload("decode_slots") >= n * 2 * kv.k_pool.nbytes
+    assert list(tix.tokens) == _oneshot([Request(0, prompt, 6)])[0]
+
+
 # -- page exhaustion --------------------------------------------------------
 
 def test_page_exhaustion_evicts_with_kv_pages_reason():
