@@ -3,11 +3,13 @@
 After the window has closed and the engine is freed, a sample of the
 requests it finished, drawn from the seed and always holding the longest,
 is run through the reference once each: prompt and served tokens as one
-sequence.  At the position that produced each served token, the number
-compared is how far the served token's reference logit lies below the
-reference's best logit there.  Greedy decoding in bf16 picks the best token
-or one within bf16's rounding of it; a wrong cache row, a lost page or a
-token altered on its way out lands far below.
+sequence, through the float32 forward that the configuration's plug-in
+names (``arch/<arch>.py``'s ``logits``).  At the position that produced
+each served token, the number compared is how far the served token's
+reference logit lies below the reference's best logit there.  Greedy
+decoding in bf16 picks the best token or one within bf16's rounding of it;
+a wrong cache row, a lost page or a token altered on its way out lands far
+below.
 
 The control puts the reference in the program's place at the precision
 step below bf16 (fp8 weights): at the same positions, the token it puts
@@ -16,11 +18,9 @@ through the same limit.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
-
-from reference import dense
 
 ROW_PAD = 128
 
@@ -43,43 +43,46 @@ def sample(finished: Sequence, seed_rng: np.random.Generator,
     return out
 
 
-def _ref_logits(weights, model, seq, rows, quant=None) -> np.ndarray:
+def _ref_logits(logits, weights, model, seq, rows, quant=None
+                ) -> np.ndarray:
     n = len(rows)
     padded = np.full(-(-n // ROW_PAD) * ROW_PAD, rows[-1], np.int32)
     padded[:n] = rows
-    out = dense.logits(weights, model, seq, padded, quant=quant)
+    out = logits(weights, model, seq, padded, quant=quant)
     return np.asarray(out)[:n]
 
 
-def gaps(weights: Dict, model: Dict, prompt: np.ndarray,
+def gaps(logits: Callable, weights: Dict, model: Dict, prompt: np.ndarray,
          served: Sequence[int], control: bool = False) -> Dict[str, float]:
-    """Widest gap below the reference's best over one request's served
-    tokens (and over the control's picks, where asked)."""
+    """Widest gap below the reference ``logits``' best over one request's
+    served tokens (and over the control's picks, where asked)."""
     served = np.asarray(served, np.int32)
     seq = np.concatenate([np.asarray(prompt, np.int32), served[:-1]])
     rows = np.arange(len(prompt) - 1, len(seq), dtype=np.int32)
-    ref = _ref_logits(weights, model, seq, rows)
+    ref = _ref_logits(logits, weights, model, seq, rows)
     best = ref.max(axis=1)
     out = {"gap": float(np.max(best - ref[np.arange(len(rows)), served]))}
     if control:
-        low = _ref_logits(weights, model, seq, rows, quant="fp8")
+        low = _ref_logits(logits, weights, model, seq, rows, quant="fp8")
         pick = low.argmax(axis=1)
         out["control_gap"] = float(np.max(best - ref[np.arange(len(rows)),
                                                      pick]))
     return out
 
 
-def compare(weights: Dict, model: Dict, requests: Sequence,
-            control: bool = False) -> Dict[str, Optional[float]]:
-    """Widest gap over a sample of requests (each with ``prompt`` and
-    ``tokens``).  With ``control`` the fp8 control's picks replace the
-    served tokens in ``max_logit_gap``; the served tokens' own gap is kept
-    as ``program_max_logit_gap``."""
+def compare(logits: Callable, weights: Dict, model: Dict,
+            requests: Sequence, control: bool = False
+            ) -> Dict[str, Optional[float]]:
+    """Widest gap below the reference ``logits`` over a sample of requests
+    (each with ``prompt`` and ``tokens``).  With ``control`` the fp8
+    control's picks replace the served tokens in ``max_logit_gap``; the
+    served tokens' own gap is kept as ``program_max_logit_gap``."""
     g: List[float] = []
     c: List[float] = []
     n_tok = 0
     for r in requests:
-        one = gaps(weights, model, r.prompt, r.tokens, control=control)
+        one = gaps(logits, weights, model, r.prompt, r.tokens,
+                   control=control)
         g.append(one["gap"])
         if control:
             c.append(one["control_gap"])

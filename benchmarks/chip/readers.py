@@ -3,14 +3,15 @@
 Every reader takes the run record that ``run.py`` builds and returns a
 number, or None where its run holds nothing to read (no trace, no launch of
 its program in the slice).  A roofline share is never returned as 0 for
-lack of data: it is None then.
+lack of data: it is None then.  The work of each launch comes from the
+record's architecture plug-in (``run.arch``, ``arch/<arch>.py``), given the
+launch's record: a prefill's start and size; a decode's live lengths,
+tokens per launch and what the plug-in noted for it.
 """
 from __future__ import annotations
 
 import math
 from typing import Iterable, List, Optional
-
-import counts
 
 DECODE = "jit_decode_paged"
 PREFILL = "jit_extend_paged"
@@ -60,7 +61,7 @@ def prefill_work(run):
     for k in _slice_steps(run):
         for start, size in run.steps[k]["prefills"]:
             tok += size
-            flops += counts.prefill_flops(run.model, start, size)
+            flops += run.arch.prefill_flops(run.model, start, size)
     return tok, flops
 
 
@@ -68,9 +69,10 @@ def decode_work(run, keys: Iterable[int]):
     """(FLOPs, bytes) of the decode launches of the given steps."""
     flops = nbytes = 0
     for k in keys:
-        lengths = run.steps[k]["decode"]
-        if lengths is not None:
-            f, b = counts.decode_flops_bytes(run.model, lengths, run.T)
+        step = run.steps[k]
+        if step["decode"] is not None:
+            f, b = run.arch.decode_flops_bytes(run.model, step["decode"],
+                                               run.T, step.get("note"))
             flops += f
             nbytes += b
     return flops, nbytes
@@ -120,7 +122,7 @@ def window_mfu(run) -> Optional[float]:
     if not keys or run.elapsed <= 0:
         return None
     df, _ = decode_work(run, keys)
-    pf = sum(counts.prefill_flops(run.model, a, n)
+    pf = sum(run.arch.prefill_flops(run.model, a, n)
              for k in keys for a, n in run.steps[k]["prefills"])
     return 100.0 * (pf + df) / (run.elapsed * run.peaks["bf16_flops_per_s"])
 

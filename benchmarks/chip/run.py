@@ -7,7 +7,8 @@
 Run from the root of a checkout on a host with a TPU.  The cell is looked up
 by name in ``BENCHMARK.json``; its configuration, traffic mix, metric
 readers and correctness limit are files under this directory, found by
-name.  The run:
+name, and its architecture's plug-in is ``arch/<arch>.py`` (see
+``arch/dense.py``).  The run:
 
 1. refuses to run without a TPU (or with fewer chips than the cell asks);
 2. builds the continuous-batching engine with every knob from the
@@ -37,6 +38,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -55,6 +57,7 @@ import check  # noqa: E402
 import readers  # noqa: E402
 import schedule  # noqa: E402
 
+ARCH_DIR = os.path.join(HERE, "arch")
 CACHE_DIR = os.path.join(ROOT, ".bench_cache", "jax")
 DRAIN_S = 60.0          # how long past the window a due request may take
 SAMPLE_TOKENS = 512     # served tokens the check compares, at least
@@ -76,7 +79,8 @@ def _json(path: str) -> Dict:
 
 
 def lookup(name: str) -> Dict[str, Any]:
-    """The cell ``name`` with its configuration, mix, limits and metrics."""
+    """The cell ``name`` with its configuration, its architecture's plug-in,
+    mix, limits and metrics."""
     bench = _json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = next((w for w in bench["workloads"] if w["name"] == name), None)
     if cell is None:
@@ -91,10 +95,12 @@ def lookup(name: str) -> Dict[str, Any]:
         moved = next(e for e in bench["end_to_end"] if e["name"] == m["moves"])
         return applies(moved)
 
+    cfg = _json(os.path.join(ROOT, conf["file"]))
     return {
         "name": name,
         "chips": int(cell["chips"]),
-        "config": _json(os.path.join(ROOT, conf["file"])),
+        "config": cfg,
+        "arch": load_arch(cfg),
         "mix": _json(os.path.join(HERE, "traffic", cell["traffic"] + ".json")),
         "limits": _json(os.path.join(HERE, "limits", name + ".json")),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
@@ -102,13 +108,30 @@ def lookup(name: str) -> Dict[str, Any]:
     }
 
 
-def load_reader(name: str) -> Callable:
-    path = os.path.join(HERE, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_"), path)
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str) -> Callable:
+    return _module(os.path.join(HERE, "metrics", name + ".py"),
+                   "metric_" + name.replace(".", "_")).read
+
+
+def load_arch(cfg: Dict):
+    """The plug-in ``arch/<arch>.py`` that the configuration names."""
+    if "arch" not in cfg:
+        raise SystemExit(f"configuration {cfg.get('name')!r} names no arch; "
+                         f"its plug-in would be {ARCH_DIR}/<arch>.py")
+    arch = str(cfg["arch"])
+    path = os.path.join(ARCH_DIR, arch + ".py")
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_-]*", arch) or \
+            not os.path.isfile(path):
+        raise SystemExit(f"configuration {cfg.get('name')!r} names arch "
+                         f"{arch!r}, but there is no plug-in {path}")
+    return _module(path, "arch_" + arch)
 
 
 # -- the device ---------------------------------------------------------------
@@ -174,60 +197,47 @@ class SpanLog:
             self.events.append(ev.meta)
 
 
-def model_config(cfg: Dict):
-    from repro.configs.base import ModelConfig
-    m = cfg["model"]
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=int(m["num_hidden_layers"]), d_model=int(m["hidden_size"]),
-        n_heads=int(m["num_attention_heads"]),
-        n_kv_heads=int(m["num_key_value_heads"]),
-        d_ff=int(m["intermediate_size"]), vocab_size=int(m["vocab_size"]),
-        head_dim=int(m.get("head_dim") or 0), qk_norm=bool(m.get("qk_norm")),
-        rope_theta=float(m["rope_theta"]), act="silu",
-        param_dtype="bfloat16", tie_embeddings=False)
-
-
-def build_engine(cfg: Dict, seed: int, spans: SpanLog):
+def build_engine(cfg: Dict, arch, seed: int, spans: SpanLog):
     """The engine with every knob from the configuration, holding weights
-    made from ``seed``; returns ``(engine, weights)``."""
+    that the plug-in ``arch`` makes from ``seed``; returns
+    ``(engine, weights)``."""
     import jax
     from repro.core.session import TraceSession
     from repro.runtime.server import ContinuousBatchingServer
-    import weights
     e = cfg["engine"]
     session = TraceSession(name="bench")
     session.add_sink(spans)
     seed32 = int(np.random.SeedSequence(
         schedule.seed_words(seed)).generate_state(1)[0])
     eng = ContinuousBatchingServer(
-        model_config(cfg), batch_size=int(e["slots"]),
+        arch.model_config(cfg), batch_size=int(e["slots"]),
         max_seq=int(e["max_seq"]),
         tokens_per_launch=int(e["tokens_per_launch"]),
         seed=seed32 & 0x7FFFFFFF,
         session=session, max_pending=int(e["max_pending"]),
-        admission=e["admission"], kv=e["kv"],
-        kv_page_tokens=int(e["page_tokens"]),
+        admission=e["admission"], kv_page_tokens=int(e["page_tokens"]),
         kv_pages=e.get("pages"), prefill_chunk=int(e["prefill_chunk"]),
         sched=e["sched"])
     want = jax.eval_shape(eng.model.init_params, jax.random.PRNGKey(0))
-    mine = weights.shapes(cfg["model"])
+    mine = arch.shapes(cfg["model"])
     tree = jax.tree_util
     if (tree.tree_structure(want) != tree.tree_structure(mine)
             or tree.tree_leaves(want) != tree.tree_leaves(mine)):
         raise RuntimeError("the engine's parameter layout differs from the "
-                           "one weights.py makes")
+                           f"one {cfg['arch']}'s plug-in makes")
     eng.params = None
     gc.collect()
-    w = weights.make(cfg["model"], seed32)
+    w = arch.make(cfg["model"], seed32)
     eng.params = w
     return eng, w
 
 
-def instrument(eng, steps_state: Dict) -> None:
+def instrument(eng, steps_state: Dict,
+               note: Optional[Callable] = None) -> None:
     """Wrap the KV manager's calls in profiler annotations, time them on the
     host (ms per call name, for the step in ``steps_state["calls"]``), and
-    note the live lengths each decode launch starts from."""
+    note the live lengths each decode launch starts from, with what the
+    plug-in's ``note(eng)``, where it has one, returns before the launch."""
     import jax
     kv = eng.kv
 
@@ -248,10 +258,16 @@ def instrument(eng, steps_state: Dict) -> None:
     def note_lengths():
         steps_state["decode"] = [int(x) for x in kv.lengths[kv.ready]]
 
+    before = note_lengths
+    if note is not None:
+        def before():
+            note_lengths()
+            steps_state["note"] = note(eng)
+
     kv.begin = wrap("engine.kv_begin", kv.begin)
     kv.prefill_step = wrap("engine.prefill", kv.prefill_step)
     kv.reserve_decode = wrap("engine.reserve", kv.reserve_decode)
-    kv.decode = wrap("engine.decode", kv.decode, note_lengths)
+    kv.decode = wrap("engine.decode", kv.decode, before)
     kv.release = wrap("engine.release", kv.release)
 
 
@@ -279,7 +295,8 @@ class Loop:
     """Drives ``submit`` and ``step`` and times every request on its own
     clock: the wall clock less the time spent in ``pause``."""
 
-    def __init__(self, eng, spans: SpanLog, clock=time.perf_counter) -> None:
+    def __init__(self, eng, spans: SpanLog, clock=time.perf_counter,
+                 note: Optional[Callable] = None) -> None:
         self.eng = eng
         self.spans = spans
         self.wall = clock
@@ -291,7 +308,7 @@ class Loop:
         self.on_step: Optional[Callable[[float], None]] = None
         # device memory after each step, to tell what a slow step did
         self.memory: Callable[[], Optional[Dict]] = lambda: None
-        instrument(eng, self.state)
+        instrument(eng, self.state, note)
 
     def clock(self) -> float:
         return self.wall() - sum(d for _, d in self.pauses)
@@ -319,7 +336,7 @@ class Loop:
     def step(self) -> bool:
         import jax
         n0 = len(self.spans.events)
-        self.state["decode"] = None
+        self.state["decode"] = self.state["note"] = None
         self.state["calls"] = {}
         t0 = self.wall()
         with jax.profiler.TraceAnnotation("bench.step", i=self.i):
@@ -334,7 +351,8 @@ class Loop:
             "iter_active": its[0] if its else None,
             "prefills": [(int(m["start"]), int(m["size"])) for m in new
                          if m["span"] != "serve.decode_iter"],
-            "decode": self.state["decode"], "calls": self.state["calls"],
+            "decode": self.state["decode"], "note": self.state["note"],
+            "calls": self.state["calls"],
             "memory": self.memory()}
         self.i += 1
         self.observe(now)
@@ -394,17 +412,16 @@ def execute(cell: Dict, seed: int, seconds: float, trace: bool,
             (time.perf_counter(), name, dur))
         if name.startswith(COMPILE_PATH) else None)
 
-    cfg, mix = cell["config"], cell["mix"]
+    cfg, mix, arch = cell["config"], cell["mix"], cell["arch"]
     e = cfg["engine"]
     spans = SpanLog()
-    eng, w = build_engine(cfg, seed, spans)
-    # The decode program reserves its temporaries when it is loaded.  Load
-    # it first, with nothing in flight: loaded behind an extend launch, it
-    # finds that launch's input and output page pools both still held.
+    eng, w = build_engine(cfg, arch, seed, spans)
+    # The decode program reserves its temporaries when it is loaded: load
+    # it first, with no other launch in flight.
     eng.kv.decode()
     if fault is not None:
         fault(eng)
-    loop = Loop(eng, spans)
+    loop = Loop(eng, spans, note=getattr(arch, "note", None))
     if require_tpu:
         loop.memory = device_memory
     vocab = int(cfg["model"]["vocab_size"])
@@ -529,7 +546,8 @@ def execute(cell: Dict, seed: int, seconds: float, trace: bool,
     rng = np.random.default_rng(schedule.seed_words(seed) + [0xC4EC])
     picked = check.sample(done, rng, SAMPLE_TOKENS, SAMPLE_REQUESTS)
     t_ref = time.perf_counter()
-    cmp = check.compare(w, cfg["model"], picked, control=control)
+    cmp = check.compare(arch.logits, w, cfg["model"], picked,
+                        control=control)
     ref_s = time.perf_counter() - t_ref
     limit = float(cell["limits"]["max_logit_gap"])
     short = sum(1 for p in picked if len(p.tokens) != p.max_new)
@@ -546,7 +564,7 @@ def execute(cell: Dict, seed: int, seconds: float, trace: bool,
                and short == 0)
 
     run = _Record(
-        model=cfg["model"], T=int(e["tokens_per_launch"]),
+        arch=arch, model=cfg["model"], T=int(e["tokens_per_launch"]),
         slots=int(e["slots"]), peaks=peaks_for(dev["kind"], require_tpu),
         requests=window if sched.kind == "open" else [], giveup=end,
         elapsed=elapsed, out_tokens=out_tokens, doorbells=doorbells,
@@ -630,6 +648,7 @@ def _gc_timer(out: List[Tuple[float, float]]) -> Callable:
 
 @dataclasses.dataclass
 class _Record:
+    arch: Any
     model: Dict
     T: int
     slots: int

@@ -24,9 +24,11 @@ def _load(name):
 
 @pytest.fixture(scope="module")
 def cell():
-    return {"name": "tiny", "chips": 1, "config": _load("tiny.json"),
-            "mix": _load("tiny-mix.json"), "limits": _load("tiny-limits.json"),
-            "end_to_end": [], "per_layer": []}
+    cfg = _load("tiny.json")
+    return {"name": "tiny", "chips": 1, "config": cfg,
+            "arch": run.load_arch(cfg), "mix": _load("tiny-mix.json"),
+            "limits": _load("tiny-limits.json"), "end_to_end": [],
+            "per_layer": []}
 
 
 def _run(cell, seed, **kw):

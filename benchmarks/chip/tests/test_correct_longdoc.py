@@ -28,9 +28,9 @@ def _load(name):
 
 @pytest.fixture(scope="module")
 def cell():
-    return {"name": "tiny-longdoc", "chips": 1,
-            "config": _load("tiny-qwen3.json"),
-            "mix": _load("tiny-longdoc.json"),
+    cfg = _load("tiny-qwen3.json")
+    return {"name": "tiny-longdoc", "chips": 1, "config": cfg,
+            "arch": run.load_arch(cfg), "mix": _load("tiny-longdoc.json"),
             "limits": _load("tiny-limits.json"),
             "end_to_end": [], "per_layer": []}
 

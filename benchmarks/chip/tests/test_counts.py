@@ -1,20 +1,31 @@
-"""counts.py against FLOPs and bytes worked out by hand."""
+"""The dense plug-in's work counts against FLOPs and bytes worked out by
+hand, each configuration's counts taken through the plug-in that it names."""
 import json
 import os
 
-import counts
+import pytest
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-FILES = {"deepseek-7b-l10": os.path.join(os.path.dirname(HERE), "configs",
-                                         "deepseek-7b-l10.json"),
-         # the grouped-query case, with no cell of its own yet
-         "qwen3-8b-l12": os.path.join(HERE, "data",
-                                      "qwen3-8b-l12-model.json")}
+import run
+
+CONFIGS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "configs")
+
+
+def _config(name):
+    with open(os.path.join(CONFIGS, name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def counts():
+    """The plug-in both configurations name (they are dense decoders)."""
+    archs = {_config(n)["arch"] for n in ("deepseek-7b-l10", "qwen3-8b-l12")}
+    assert archs == {"dense"}
+    return run.load_arch(_config("deepseek-7b-l10"))
 
 
 def model(name):
-    with open(FILES[name]) as f:
-        return json.load(f)["model"]
+    return _config(name)["model"]
 
 
 # DeepSeek-LLM-7B cut to 10 layers: d 4096, 32 heads of 128 (MHA), ff 11008,
@@ -27,12 +38,12 @@ DS_LAYER = 202_375_168
 QW_LAYER = 192_937_984
 
 
-def test_layer_weights():
+def test_layer_weights(counts):
     assert counts.layer_params(model("deepseek-7b-l10")) == DS_LAYER
     assert counts.layer_params(model("qwen3-8b-l12")) == QW_LAYER
 
 
-def test_deepseek_decode_one_slot_at_1000():
+def test_deepseek_decode_one_slot_at_1000(counts):
     """One token for one slot whose cache holds 1000 positions.
 
     FLOPs: 2*10*DS_LAYER (4,047,503,360) + logits 2*4096*102400
@@ -40,12 +51,13 @@ def test_deepseek_decode_one_slot_at_1000():
     Bytes: weights 2*(10*DS_LAYER + 4096*102400) (4,886,364,160) + KV at
     the live length and the new row 1001*163,840 (164,003,840) + one
     embedding row 2*4096 (8,192)."""
-    f, b = counts.decode_flops_bytes(model("deepseek-7b-l10"), [1000], 1)
+    f, b = counts.decode_flops_bytes(model("deepseek-7b-l10"), [1000], 1,
+                                     None)
     assert f == 4_047_503_360 + 838_860_800 + 164_003_840 == 5_050_368_000
     assert b == 4_886_364_160 + 164_003_840 + 8_192 == 5_050_376_192
 
 
-def test_deepseek_prefill_first_chunk():
+def test_deepseek_prefill_first_chunk(counts):
     """512 prompt positions from 0: linear 2*10*DS_LAYER*512, attention
     4*10*32*128 over 512*513/2 causal pairs, logits of the last position."""
     f = counts.prefill_flops(model("deepseek-7b-l10"), 0, 512)
@@ -53,7 +65,7 @@ def test_deepseek_prefill_first_chunk():
     assert f == 2_094_677_360_640
 
 
-def test_qwen_prefill_third_chunk():
+def test_qwen_prefill_third_chunk(counts):
     """512 positions from 1024: each attends to 1024 earlier positions and
     its causal part of the chunk, 512*1024 + 512*513/2 = 655,616 pairs."""
     f = counts.prefill_flops(model("qwen3-8b-l12"), 1024, 512)
@@ -64,14 +76,14 @@ def test_qwen_prefill_third_chunk():
     assert f == linear + attn + logits == 2_500_965_957_632
 
 
-def test_qwen_decode_two_slots_four_steps():
+def test_qwen_decode_two_slots_four_steps(counts):
     """Slots at 100 and 3000 positions, T=4: every step reads the weights
     once (2*(12*QW_LAYER + 4096*151936) = 5,875,171,328 bytes) and each
     slot's KV at its live length, 49,152 bytes a position (12 layers, K and
     V, 8 heads of 128, bf16)."""
     m = model("qwen3-8b-l12")
     assert counts.kv_bytes_per_token(m) == 49_152
-    f, b = counts.decode_flops_bytes(m, [100, 3000], 4)
+    f, b = counts.decode_flops_bytes(m, [100, 3000], 4, None)
     weights = 4 * 5_875_171_328
     kv = sum((ln + t + 1) * 49_152 for ln in (100, 3000) for t in range(4))
     rows = 8 * 2 * 4096

@@ -104,9 +104,11 @@ def test_run_on_the_cpu_records_the_window_steps():
     def load(name):
         with open(os.path.join(DATA, name)) as f:
             return json.load(f)
-    cell = {"name": "tiny", "chips": 1, "config": load("tiny.json"),
-            "mix": load("tiny-mix.json"), "limits": load("tiny-limits.json"),
-            "end_to_end": [], "per_layer": []}
+    cfg = load("tiny.json")
+    cell = {"name": "tiny", "chips": 1, "config": cfg,
+            "arch": run.load_arch(cfg), "mix": load("tiny-mix.json"),
+            "limits": load("tiny-limits.json"), "end_to_end": [],
+            "per_layer": []}
     log = phases.StepSpans()
     res = run.execute(cell, 1001, 1.0, False, require_tpu=False,
                       fault=log.attach)

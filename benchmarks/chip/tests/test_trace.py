@@ -13,9 +13,12 @@ plane, in the event-list form ``xtrace.load`` returns:
 """
 import json
 import os
+from types import SimpleNamespace
 
 import pytest
 
+import readers
+import run
 import xtrace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -66,6 +69,36 @@ def test_idle_gaps_named_by_the_host(reduced):
     assert gaps[0] == ["bench.wait", pytest.approx(0.150)]
     assert sorted(g[0] for g in gaps[1:]) == ["bench.step", "engine.decode",
                                               "engine.prefill"]
+
+
+def test_rooflines_count_the_slice_through_the_plugin(reduced):
+    """The slice's work, counted by the plug-in the configuration names, at
+    DeepSeek-7B-l10's widths, T = 4: decode launches from [600] (step 1) and
+    [700, 1800] (step 3), and one extend chunk of 512 from 1024 (step 3).
+
+    Decode bytes (``test_counts.py``'s terms): weights 4,886,364,160 a
+    step; step 1 adds (601+...+604)*163,840 of KV and 4 rows of 8,192,
+    19,940,343,808 in all; step 3 (701..704, 1801..1804) 21,187,198,976.
+    Extend FLOPs: linear 2,072,321,720,320, attention 4*10*32*128*655,616
+    = 107,416,125,440, logits 838,860,800.  Device time: decode 90 ms,
+    extend 30 ms (``test_program_time_per_step``)."""
+    with open(os.path.join(os.path.dirname(DATA), os.pardir, "configs",
+                           "deepseek-7b-l10.json")) as f:
+        cfg = json.load(f)
+    steps = {k: dict(v, prefills=[], note=None) for k, v in STEPS.items()}
+    steps[1]["decode"], steps[3]["decode"] = [600], [700, 1800]
+    steps[3]["prefills"] = [(1024, 512)]
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    rec = SimpleNamespace(arch=run.load_arch(cfg), model=cfg["model"], T=4,
+                          steps=steps, trace=reduced, peaks=peaks)
+    nbytes = 19_940_343_808 + 21_187_198_976
+    flops = 2_072_321_720_320 + 107_416_125_440 + 838_860_800
+    assert readers.decode_work(rec, reduced["steps"])[1] == nbytes
+    assert readers.prefill_work(rec) == (512, flops)
+    assert readers.decode_hbm_roofline(rec) == pytest.approx(
+        100 * nbytes / 819e9 / 0.090, rel=1e-12)
+    assert readers.prefill_flop_roofline(rec) == pytest.approx(
+        100 * flops / 197e12 / 0.030, rel=1e-12)
 
 
 def test_nothing_to_read():
